@@ -26,7 +26,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .quantizer import QuantizerConfig, _next_bits, header_bits
+from .quantizer import QuantizerConfig, _next_bits, header_bits, levels_of
 
 Array = jax.Array
 
@@ -114,7 +114,7 @@ def dequantize_rows(qlev: Array, hat_prev: Array, radius: Array,
     function, so both ends of a link stay bit-identical by construction.
     qlev: (..., d) levels, hat_prev: (..., d), radius/bits: (...,) per row.
     """
-    levels = 2.0 ** bits.astype(jnp.float32) - 1.0
+    levels = levels_of(bits)
     safe_r = jnp.maximum(radius, 1e-30)[..., None]
     step = 2.0 * safe_r / levels[..., None]
     hat_new = hat_prev + step * qlev - radius[..., None]
@@ -139,7 +139,7 @@ def quantize_rows(theta: Array, hat_prev: Array, active: Array, key: Array,
     # (same dedup pattern as header_bits for the payload accounting).
     b_new = jnp.broadcast_to(
         _next_bits(cfg.qcfg, bits_prev, r_new, radius_prev), (n,))
-    levels = 2.0 ** b_new.astype(jnp.float32) - 1.0
+    levels = levels_of(b_new)
     safe_r = jnp.maximum(r_new, 1e-30)[:, None]
     step = 2.0 * safe_r / levels[:, None]
     c = (diff + r_new[:, None]) / step

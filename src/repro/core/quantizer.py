@@ -203,6 +203,16 @@ def init_state(theta: Any, cfg: QuantizerConfig) -> QuantState:
     return QuantState(theta_hat=zeros, radius=radius, bits=jnp.asarray(cfg.bits, jnp.int32))
 
 
+def levels_of(bits) -> Array:
+    """2^b - 1 as f32, exactly, from an integer bit width (traced or not).
+
+    Built by an integer shift, so the level grid never rests on how a
+    backend evaluates a traced f32 `2.0 ** b`; the sender, the receivers
+    and the reference all take their levels from here."""
+    b = jnp.asarray(bits).astype(jnp.int32)
+    return (jnp.left_shift(jnp.ones_like(b), b) - 1).astype(jnp.float32)
+
+
 def _next_bits(cfg: QuantizerConfig, bits_prev: Array, r_new: Array,
                r_prev: Array, base_bits: Array | None = None) -> Array:
     """Bit-growth rule (eq. 11): smallest b s.t. Delta^k <= Delta^{k-1}.
@@ -217,7 +227,7 @@ def _next_bits(cfg: QuantizerConfig, bits_prev: Array, r_new: Array,
     if not cfg.adapt_bits:
         return jnp.broadcast_to(base, jnp.broadcast_shapes(
             base.shape, jnp.shape(r_new)))
-    levels_prev = (2.0 ** bits_prev.astype(jnp.float32)) - 1.0
+    levels_prev = levels_of(bits_prev)
     ratio = jnp.where(r_prev > 0, r_new / jnp.maximum(r_prev, 1e-30), 0.0)
     needed = jnp.ceil(jnp.log2(1.0 + levels_prev * ratio))
     b = jnp.clip(needed.astype(jnp.int32), 1, cfg.max_bits)
@@ -243,7 +253,7 @@ def quantize_tensor(
     same contract.
     """
     delta_theta = theta.astype(jnp.float32) - theta_hat_prev.astype(jnp.float32)
-    levels = (2.0 ** bits.astype(jnp.float32)) - 1.0
+    levels = levels_of(bits)
     # Guard R == 0 (already converged / first step with theta == theta_hat):
     # then q is all-zero and theta_hat is unchanged.
     safe_r = jnp.maximum(radius, 1e-30)
@@ -268,7 +278,7 @@ def dequantize_tensor(
     bits: Array,
 ) -> Array:
     """Reconstruction (eq. 13) on the receiver side."""
-    levels = (2.0 ** bits.astype(jnp.float32)) - 1.0
+    levels = levels_of(bits)
     safe_r = jnp.maximum(radius, 1e-30)
     step = 2.0 * safe_r / levels
     out = theta_hat_prev.astype(jnp.float32) + step * q.astype(jnp.float32) - radius

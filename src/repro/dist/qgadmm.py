@@ -23,10 +23,12 @@ codec's pad/reshape/slice internals away from the SPMD partitioner, which
 XLA:CPU miscompiles; see the RoPE note in dist.sharding).
 `DistConfig.wire_impl` selects the codec implementation — 'jnp' (pure-jnp
 reference), 'pallas' (Pallas kernels from repro.kernels.{quantize,pack} in
-interpret mode, for CPU), or 'pallas_compiled' (compiled Pallas, TPU).
+interpret mode, CPU only), or 'pallas_compiled' (compiled Pallas, TPU;
+repro.launch.train selects it whenever the backend is a TPU).
 All three consume one shared uniform draw over the wire buffer, so they
-are bit-identical; per_tensor radius mode expands its per-leaf radii into
-per-element values with a segment-scalar gather before the fused call.
+are bit-identical in q; every sender takes its new hat from the receivers'
+own decode of q (`_decode`).  per_tensor radius mode expands its per-leaf
+radii into per-element values with a segment-scalar gather before the codec.
 When the effective bit width is <= 4 each device nibble-packs its shard
 (kernels/pack wire format, `packed_len` bytes per shard) right before the
 jax.lax.ppermute, halving the bytes on the interconnect; `pack_wire=None`
@@ -103,16 +105,16 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import censor as censor_mod
 from repro.core.censor import CensorConfig
 from repro.core.gadmm import GADMMConfig
 from repro.core.quantizer import (LayerwiseConfig, _next_bits, allocate_bits,
-                                  header_bits)
+                                  header_bits, levels_of)
 from repro.core.topology import (Topology, build_topology, edge_index,
                                  edge_schedule)
+from repro.kernels import check_interpret
 from repro.kernels.pack import ops as pack_ops
 from repro.kernels.pack.ref import packed_len
 from repro.kernels.quantize import quantize as q_kernel
@@ -149,8 +151,9 @@ class DistConfig:
     seq_shard:   additionally shard the batch sequence dim over 'model'.
     wire_impl:   codec for the fused quantize/pack wire path — 'jnp'
                  (pure-jnp reference), 'pallas' (kernels in interpret mode,
-                 CPU), 'pallas_compiled' (compiled Pallas, TPU).  All three
-                 are bit-identical (shared uniform-draw convention).
+                 CPU only: refused on a TPU backend), 'pallas_compiled'
+                 (compiled Pallas, TPU).  All three are bit-identical
+                 (shared uniform-draw convention).
     overlap:     double-buffer the gauss-seidel exchange: tails run their
                  local iterations against the previous neighbor hats while
                  the heads' payload is in flight (one-exchange staleness).
@@ -246,6 +249,7 @@ class DistConfig:
         build_topology(self.topology, self.num_workers)  # validate early
         assert self.wire_impl in ("jnp", "pallas", "pallas_compiled"), \
             self.wire_impl
+        check_interpret(self.wire_impl == "pallas")
         assert not (self.overlap and self.mode != "gauss-seidel"), \
             "overlap (double-buffered exchange) only applies to the " \
             "two-phase gauss-seidel mode"
@@ -371,6 +375,39 @@ def _bmask(m: Array, leaf: Array) -> Array:
     return m.reshape(m.shape + (1,) * (leaf.ndim - m.ndim))
 
 
+def _rows(x: Array, idx, sliced: bool = True) -> Array:
+    """x[idx] along axis 0 for a static index vector; -1 selects a zero row.
+
+    sliced: built from static slices, not a gather — XLA:TPU takes minutes
+    to compile a gather whose slices are whole rows of a wide wire buffer or
+    slab.  Where the result is sharded over its rows, the SPMD partitioner
+    expands each slice on its own and the HLO grows as O(W^2), so such a
+    caller asks for the one gather (sliced=False)."""
+    idx = np.asarray(idx)
+    if not sliced:
+        out = x[jnp.asarray(np.maximum(idx, 0), jnp.int32)]
+        if (idx < 0).any():
+            out = jnp.where(_bmask(jnp.asarray(idx >= 0), out), out,
+                            jnp.zeros_like(out))
+        return out
+    parts = [jnp.zeros_like(x[:1]) if i < 0 else x[i:i + 1]
+             for i in idx.tolist()]
+    if not parts:
+        return x[:0]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+def _decode(q: Array, hat_f: Array, radius: Array, levels: Array) -> Array:
+    """The dequantize arithmetic of the wire (f32): levels q against the
+    previous hat, R == 0 a no-op.  The one definition shared by the sender
+    (its committed hat) and every receiver (its copy), so both ends of an
+    edge hold bitwise-equal hats by construction."""
+    safe_r = jnp.maximum(radius, 1e-30)
+    step = 2.0 * safe_r / levels
+    out = hat_f + step * q.astype(jnp.float32) - radius
+    return jnp.where(radius > 0, out, hat_f)
+
+
 def _twhere(m: Array, a, b):
     return jax.tree.map(lambda x, y: jnp.where(_bmask(m, x), x, y), a, b)
 
@@ -411,8 +448,6 @@ class QGADMMTrainer:
         self.topo: Topology = build_topology(dcfg.topology, dcfg.num_workers)
         pmask_np = self.topo.port >= 0                   # (W, C) static
         self.pmask = jnp.asarray(pmask_np, jnp.float32)
-        self.port_on = [jnp.asarray(pmask_np[:, c])
-                        for c in range(self.topo.num_ports)]
         self.is_head = jnp.asarray(self.topo.head_mask)
         self.sign = jnp.where(self.is_head, 1.0, -1.0).astype(jnp.float32)
         # Directed-edge tables for the O(E) neighbor-state slabs.
@@ -420,13 +455,6 @@ class QGADMMTrainer:
         self._d_src = jnp.asarray(self.eidx.src, jnp.int32)    # (2E,)
         self._d_dst = jnp.asarray(self.eidx.dst, jnp.int32)    # (2E,)
         self._d_sign = jnp.asarray(self.eidx.sign_dst)         # (2E,) f32
-        self._d_color = jnp.asarray(self.eidx.color, jnp.int32)  # (2E,)
-        slot = self.eidx.slot                                  # (W, C) np
-        ports = self.topo.num_ports
-        # slot clamped to 0 for the port-view gather (masked to zeros after)
-        self._view_idx = [jnp.asarray(np.where(slot[:, c] >= 0, slot[:, c],
-                                               0), np.int32)
-                          for c in range(ports)]
         # layerwise: per-leaf tables cache + the per-leaf eq. 11 config
         self._lw_cache: dict = {}
         lw = dcfg.layerwise
@@ -457,19 +485,18 @@ class QGADMMTrainer:
             tree)
 
     # ------------------------------------------------------------ views ----
-    def _port_view(self, slab):
+    def _port_view(self, slab, sharded: bool = False):
         """Edge-slab pytree (2E, ...) -> tuple over edge colors of stacked
         (W, ...) trees (the port-dense layout the per-worker local loss is
-        written against).  Exact: active rows are gathered slab rows,
+        written against).  Exact: active rows are the stored slab rows,
         missing ports read as the zeros those rows always held in the
-        port-dense layout."""
-        outs = []
-        for c in range(self.topo.num_ports):
-            idx, on = self._view_idx[c], self.port_on[c]
-            outs.append(jax.tree.map(
-                lambda s: jnp.where(_bmask(on, s[idx]), s[idx],
-                                    jnp.zeros_like(s[idx])), slab))
-        return tuple(outs)
+        port-dense layout.  In the sharded step the view is sharded over
+        'worker', so it is a gather there (see _rows: static slices took
+        the W=16 chain step from 6 s to 608 s to compile on XLA:CPU)."""
+        return tuple(
+            jax.tree.map(lambda s: _rows(s, self.eidx.slot[:, c],
+                                         sliced=not sharded), slab)
+            for c in range(self.topo.num_ports))
 
     def port_views(self, state: DistState) -> dict:
         """Public projection of the edge-indexed neighbor state back to the
@@ -624,24 +651,15 @@ class QGADMMTrainer:
         topo = self.topo
         ports = topo.num_ports
         if not sharded:
-            # Unsharded reference: gather by the partner table; packing
+            # Unsharded reference: select by the partner table; packing
             # would be an exact roundtrip (contract-tested in
             # tests/test_kernels.py), so the levels move unpacked.
             partner = topo.port  # (W, C) int, -1 where no edge
-            idxs = [jnp.asarray(np.where(partner[:, c] >= 0, partner[:, c],
-                                         np.arange(w)))
-                    for c in range(ports)]
-            masks = [jnp.asarray(partner[:, c] >= 0) for c in range(ports)]
 
             def exchange(payload):
-                outs = []
-                for c in range(ports):
-                    idx, m = idxs[c], masks[c]
-                    outs.append(jax.tree.map(
-                        lambda x: jnp.where(
-                            _bmask(m, x), jnp.take(x, idx, axis=0),
-                            jnp.zeros_like(x)), payload))
-                return tuple(outs)
+                return tuple(
+                    jax.tree.map(lambda x: _rows(x, partner[:, c]), payload)
+                    for c in range(ports))
             return exchange
 
         mesh = self.mesh
@@ -679,9 +697,9 @@ class QGADMMTrainer:
                                  p, packed_leaves)
                     for perm in perms)
 
-            return shard_map(body, mesh=mesh, in_specs=(specs,),
-                             out_specs=(specs,) * ports,
-                             check_rep=False)(payload)
+            return jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                 out_specs=(specs,) * ports,
+                                 check_vma=False)(payload)
 
         return exchange
 
@@ -719,22 +737,29 @@ class QGADMMTrainer:
         return jnp.stack(cols, axis=1)
 
     def _qdq_row(self, theta_row, hat_row, u_row, radius, bits):
-        """One fused quantize-dequantize call on one (d,) wire-row slab.
-        radius is a scalar (global mode) or a (d,) per-element expansion
-        (per_tensor mode); bits is a scalar or a (d,) per-element expansion
-        (layerwise per-leaf widths)."""
-        levels = (2.0 ** bits.astype(jnp.float32)) - 1.0
+        """Quantize one (d,) wire-row slab -> (q, new hat).  radius is a
+        scalar (global mode) or a (d,) per-element expansion (per_tensor
+        mode); bits is a scalar or a (d,) per-element expansion (layerwise
+        per-leaf widths).
+
+        The codec yields q only (the Pallas `quantize` kernel or the jnp
+        reference's q); the sender's new hat is `_decode(q)`, the function
+        every receiver applies to the same q, so sender==receiver bit-sync
+        never rests on Mosaic and XLA rounding the dequantize alike."""
+        levels = levels_of(bits)
         radius = jnp.asarray(radius, jnp.float32)
         if self.dcfg.wire_impl == "jnp":
-            return q_ref.quantize_dequantize_ref(
+            q, _ = q_ref.quantize_dequantize_ref(
                 theta_row, hat_row, u_row, radius, levels)
-        return q_kernel.quantize_dequantize(
-            theta_row, hat_row, u_row, radius, levels,
-            interpret=self.dcfg.wire_impl != "pallas_compiled")
+        else:
+            q = q_kernel.quantize(
+                theta_row, hat_row, u_row, radius, levels,
+                interpret=self.dcfg.wire_impl != "pallas_compiled")
+        return q, _decode(q, hat_row, radius, levels)
 
     def _qdq_sharded(self, theta_f, hat_f, u, radius, bits, seg=None):
-        """Codec under shard_map: every device runs one fused
-        quantize-dequantize on exactly the (1, d_loc) wire slab it owns,
+        """Codec under shard_map: every device runs the quantize (and the
+        shared decode of its hat) on exactly the (1, d_loc) wire slab it owns,
         with its worker's radius/bits riding along the 'worker' axis.
 
         This keeps the codec internals out of the SPMD partitioner — which
@@ -784,10 +809,10 @@ class QGADMMTrainer:
             q, hh = self._qdq_row(th[0], h[0], uu[0], rr_row, bb_row)
             return q[None], hh[None]
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(bspec, bspec, bspec, rspec, bitspec),
-            out_specs=(bspec, bspec), check_rep=False)(
+            out_specs=(bspec, bspec), check_vma=False)(
                 theta_f, hat_f, u, radius, bits)
 
     def _quantize_all(self, theta, hat, bits_prev, radius_prev, key,
@@ -901,8 +926,8 @@ class QGADMMTrainer:
 
     def _dequantize_all(self, q_wire, hat_copy, radius, bits):
         """Receiver-side reconstruction on the flat wire buffer against the
-        stored neighbor hats — identical f32 arithmetic (and per-leaf final
-        cast) to the sender's fused kernel, preserving bit-sync."""
+        stored neighbor hats — the same `_decode` (and per-leaf final cast)
+        the sender commits its own hat with, preserving bit-sync."""
         treedef = jax.tree.structure(hat_copy)
         hat_leaves = treedef.flatten_up_to(hat_copy)
         hat_f = self._flatten_rows(hat_leaves, jnp.float32)    # (W, D)
@@ -911,15 +936,12 @@ class QGADMMTrainer:
         sizes = _leaf_sizes(hat_leaves)
         seg = np.repeat(np.arange(len(sizes)), sizes)
         if bits.ndim == 1:
-            levels = ((2.0 ** bits.astype(jnp.float32)) - 1.0)[:, None]
+            levels = levels_of(bits)[:, None]
         else:
             # layerwise per-leaf widths -> per-position levels
-            levels = (2.0 ** bits[:, seg].astype(jnp.float32)) - 1.0
+            levels = levels_of(bits[:, seg])
         r_pos = radius[:, None] if radius.ndim == 1 else radius[:, seg]
-        safe_r = jnp.maximum(r_pos, 1e-30)
-        step = 2.0 * safe_r / levels
-        out = hat_f + step * q_wire.astype(jnp.float32) - r_pos
-        out = jnp.where(r_pos > 0, out, hat_f)
+        out = _decode(q_wire, hat_f, r_pos, levels)
         return self._unflatten_cast(out, hat_leaves, treedef)
 
     # ------------------------------------------------------------- step ----
@@ -1021,8 +1043,8 @@ class QGADMMTrainer:
         (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
         # project the edge slabs to the per-(worker, color) port views the
         # per-worker local loss is written against (exact; see _port_view)
-        hat_nbr = self._port_view(hat_edge)
-        lam_nbr = self._port_view(lam_edge)
+        hat_nbr = self._port_view(hat_edge, sharded)
+        lam_nbr = self._port_view(lam_edge, sharded)
         new_theta, new_mu, new_nu, new_t, f0 = jax.vmap(self._local_opt)(
             theta, mu, nu, t, batch, lam_nbr, hat_nbr, pw, self.sign)
         theta = _twhere(active, new_theta, theta)
@@ -1118,7 +1140,7 @@ class QGADMMTrainer:
         the stored hat untouched — exactly what their own rolled-back
         state holds, preserving bit-sync.  Directed row d is served by
         the payload worker dst[d] received on port color[d], so the whole
-        slab commits as ONE uniform gather + decode + where over the 2E
+        slab commits as ONE uniform row selection + decode over the 2E
         rows — one decode per directed edge, O(E) work instead of the
         port-dense O(W*C).
 
@@ -1128,7 +1150,7 @@ class QGADMMTrainer:
         sharded step — O(1) absolute garbage in the committed rows once
         the slab was nonzero (same bug family as the RoPE and
         in-shard-codec notes; sharding pins on the operands did NOT fix
-        the fused program).  The uniform gather/where form avoids the
+        the fused program).  The uniform full-slab form avoids the
         scatter entirely.  sharded=True additionally pins the decode's
         operands replicated — the slabs are O(E*D) and every worker
         stores them anyway, so that is the intended semantics, not a
@@ -1139,25 +1161,19 @@ class QGADMMTrainer:
             return st
         if sharded:
             recv, hat_edge = self._replicate((recv, hat_edge))
-        col, dst = self._d_color, self._d_dst
 
         def pick(k):
-            # (C, W, ...) stacked payloads -> per-directed-row (2E, ...)
-            return jnp.stack([r[k] for r in recv])[col, dst]
+            # per-color (W, ...) payloads -> per-directed-row (2E, ...)
+            return jnp.concatenate([
+                recv[c][k][w:w + 1]
+                for c, w in zip(self.eidx.color.tolist(),
+                                self.eidx.dst.tolist())])
 
-        got = pick("sent")
-        wire = pick("wire")
-        if g.quantize:
-            d = sum(_leaf_sizes(jax.tree.leaves(theta)))
-            dec = self._dequantize_all(self._strip_wire(wire, d), hat_edge,
-                                       pick("radius"), pick("bits"))
-        else:
-            treedef = jax.tree.structure(hat_edge)
-            leaves = treedef.flatten_up_to(hat_edge)
-            ls = self._unflatten_wire(wire, leaves)
-            dec = jax.tree.unflatten(
-                treedef, [l.astype(r.dtype) for l, r in zip(ls, leaves)])
-        hat_edge = _twhere(got, dec, hat_edge)
+        d = sum(_leaf_sizes(jax.tree.leaves(theta)))
+        side = ((pick("radius"), pick("bits")) if g.quantize
+                else (None, None))
+        hat_edge = self._decode_rows(self._strip_wire(pick("wire"), d),
+                                     hat_edge, pick("sent"), *side)
         return (theta, hat, hat_edge, lam_edge, radius, bits,
                 mu, nu, t)
 
@@ -1186,7 +1202,7 @@ class QGADMMTrainer:
                 else self._d_sign * edge_mask)   # (2E,) f32
         scale = g.alpha * g.rho
         g_hat = self._replicate(hat) if sharded else hat
-        own = jax.tree.map(lambda a: a[self._d_dst], g_hat)
+        own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), g_hat)
         lam_edge = jax.tree.map(
             lambda l, a, b: l + scale * _bmask(coef, l).astype(l.dtype)
             * (a.astype(l.dtype) - b.astype(l.dtype)),
@@ -1206,7 +1222,6 @@ class QGADMMTrainer:
                 f"mesh worker axis {self.mesh.shape['worker']} != "
                 f"num_workers {w}")
         is_head = self.is_head
-        port_on = self.port_on
         all_on = jnp.ones((w,), bool)
         exchange = (self._make_exchange(sharded) if topo.num_edges else None)
         phase_compute = functools.partial(self.phase_compute, sharded=sharded)
@@ -1313,7 +1328,7 @@ class QGADMMTrainer:
             if self.eidx.num_directed:
                 m = self._d_sign > 0
                 g_hat = self._replicate(hat) if sharded else hat
-                own = jax.tree.map(lambda a: a[self._d_dst], g_hat)
+                own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), g_hat)
                 resid_sq = resid_sq + sum(jax.tree.leaves(jax.tree.map(
                     lambda a, b: jnp.sum(_bmask(m, a)
                                          * (a.astype(jnp.float32)
@@ -1387,18 +1402,25 @@ class QGADMMTrainer:
         return step
 
     # ------------------------------------------------- staleness pipeline --
-    def _decode_rows(self, wire, prev, radius, bits):
-        """Decode stripped wire rows against stored prev rows — the shared
-        recv-done arithmetic for neighbor slab rows and the own-hat lag
-        (identical to the barriered path's _dequantize_all, so a staleness
-        pipeline replays the exact bytes the S=0 exchange would)."""
+    def _decode_rows(self, wire, prev, sent, radius=None, bits=None):
+        """Decode stripped wire rows against stored prev rows; rows whose
+        sender stayed silent (sent False) keep prev.  The shared decode of
+        the barriered exchange (neighbor slab rows) and of the staleness
+        pipeline's recv-done (slab rows and the own-hat lag), so a
+        pipeline replays the exact bytes the S=0 exchange would.
+
+        On the quantized wire a silent row decodes with R = 0, the codec's
+        no-op, which returns prev bitwise: no select over the whole slabs,
+        which XLA:TPU compiles very slowly."""
         if self.dcfg.gadmm.quantize:
-            return self._dequantize_all(wire, prev, radius, bits)
+            return self._dequantize_all(
+                wire, prev, jnp.where(_bmask(sent, radius), radius, 0.0),
+                bits)
         treedef = jax.tree.structure(prev)
         leaves = treedef.flatten_up_to(prev)
         ls = self._unflatten_wire(wire, leaves)
-        return jax.tree.unflatten(
-            treedef, [l.astype(r.dtype) for l, r in zip(ls, leaves)])
+        return _twhere(sent, jax.tree.unflatten(
+            treedef, [l.astype(r.dtype) for l, r in zip(ls, leaves)]), prev)
 
     def _stale_round(self, st, batch, state: DistState, hat_lag, k1, k2,
                      sharded: bool, part=None, port_weights=None,
@@ -1423,17 +1445,15 @@ class QGADMMTrainer:
             # same SPMD-partitioner pin as phase_apply(sharded=True)
             entry, hat_edge, hat_lag = self._replicate(
                 (entry, hat_edge, hat_lag))
-        sent_e = entry["sent"][self._d_src]                    # (2E,)
-        dec_e = self._decode_rows(
-            entry["wire"][self._d_src], hat_edge,
-            entry["radius"][self._d_src], entry["bits"][self._d_src])
-        hat_edge = _twhere(sent_e, dec_e, hat_edge)
+        by_src = jax.tree.map(lambda a: _rows(a, self.eidx.src), entry)
+        hat_edge = self._decode_rows(by_src["wire"], hat_edge,
+                                     by_src["sent"], by_src["radius"],
+                                     by_src["bits"])
         # own-hat snapshot, decoded from the SAME payload stream the
         # neighbors decode — hat_lag[w] stays bitwise-equal to every
         # hat_edge row with src=w, so dual mirrors cannot drift
-        dec_lag = self._decode_rows(entry["wire"], hat_lag,
+        hat_lag = self._decode_rows(entry["wire"], hat_lag, entry["sent"],
                                     entry["radius"], entry["bits"])
-        hat_lag = _twhere(entry["sent"], dec_lag, hat_lag)
         st = (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t)
 
         # ---- compute: both phases against the S-stale hats -----------
@@ -1459,7 +1479,7 @@ class QGADMMTrainer:
             if edge_part is not None:
                 coef = coef * edge_part
             scale = dcfg.gadmm.alpha * dcfg.gadmm.rho
-            own = jax.tree.map(lambda a: a[self._d_dst], hat_lag)
+            own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), hat_lag)
             lam_edge = jax.tree.map(
                 lambda l, a, b: l + scale * _bmask(coef, l).astype(l.dtype)
                 * (a.astype(l.dtype) - b.astype(l.dtype)),

@@ -1,9 +1,17 @@
 """Pallas TPU kernel: int4 nibble pack / unpack.
 
 For b <= 4 quantizer bits the wire payload halves again by packing two levels
-per byte before the collective-permute.  Elementwise VPU work; blocks are
-(BLOCK_M, 2, 128) uint8 in VMEM.  Wire format (strided pairing, padded) is
-defined in ref.py; kernel and oracle produce bit-identical buffers.
+per byte before the collective-permute.  Elementwise VPU work on lane-dense
+uint8 blocks: the level stream is viewed as (2 * rows, 128), whose rows 2r
+and 2r + 1 are the low and high nibbles of packed row r.  Wire format
+(strided pairing, padded) is defined in ref.py; kernel and oracle produce
+bit-identical buffers.
+
+Mosaic has no shifts on 8-bit vectors, so the nibble arithmetic runs in
+int32 lanes and narrows to uint8 at the store.  The (2 * rows, 128) view
+(rather than (rows, 256) or (rows, 2, 128)) keeps the reshapes around the
+kernel cheap for XLA:TPU: reshaping a (1, n) wire row to (rows, 256)
+uint8 takes its compiler tens of seconds at whisper-tiny's width.
 """
 from __future__ import annotations
 
@@ -13,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .ref import LANES, _pad_rows, take_levels
+from repro.kernels import check_interpret, take_flat
+
+from .ref import LANES, _pad_rows
 
 Array = jax.Array
 
@@ -21,42 +31,44 @@ BLOCK_M = 256
 
 
 def _pack_kernel(q_ref, out_ref):
-    q = q_ref[...]  # (bm, 2, 128) uint8
+    # (2 * bm, 128) -> (bm, 2, 128): [:, 0] low, [:, 1] high nibbles
+    q = q_ref[...].astype(jnp.int32).reshape(out_ref.shape[0], 2, LANES)
     out_ref[...] = (q[:, 0, :] | (q[:, 1, :] << 4)).astype(jnp.uint8)
 
 
 def _unpack_kernel(p_ref, out_ref):
-    p = p_ref[...]  # (bm, 128) uint8
-    lo = (p & 0xF).astype(jnp.uint8)
-    hi = (p >> 4).astype(jnp.uint8)
-    out_ref[...] = jnp.stack([lo, hi], axis=1)  # (bm, 2, 128)
+    p = p_ref[...].astype(jnp.int32)  # (bm, 128)
+    lo_hi = jnp.stack([p & 0xF, p >> 4], axis=1)  # (bm, 2, 128)
+    out_ref[...] = lo_hi.reshape(out_ref.shape).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pack4(q: Array, *, interpret: bool = True) -> Array:
     """Pack flat uint8 levels (<16) into the wire format (128*ceil(n/256) bytes)."""
+    check_interpret(interpret)
     flat = q.reshape(-1)
     rows = _pad_rows(flat.size)
     pad = rows * 2 * LANES - flat.size
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), jnp.uint8)])
-    q3 = flat.reshape(rows, 2, LANES)
+    q2 = flat.reshape(2 * rows, LANES)
     block_m = min(BLOCK_M, rows)
     grid = (-(-rows // block_m),)
     out = pl.pallas_call(
         _pack_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((block_m, 2, LANES), lambda i: (i, 0, 0))],
+        in_specs=[pl.BlockSpec((2 * block_m, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_m, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.uint8),
         interpret=interpret,
-    )(q3)
+    )(q2)
     return out.reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
 def unpack4(packed: Array, n: int, *, interpret: bool = True) -> Array:
     """Unpack the wire format back to the first n uint8 levels."""
+    check_interpret(interpret)
     rows = _pad_rows(n)
     p2 = packed.reshape(rows, LANES)
     block_m = min(BLOCK_M, rows)
@@ -65,10 +77,9 @@ def unpack4(packed: Array, n: int, *, interpret: bool = True) -> Array:
         _unpack_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_m, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_m, 2, LANES), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, 2, LANES), jnp.uint8),
+        out_specs=pl.BlockSpec((2 * block_m, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((2 * rows, LANES), jnp.uint8),
         interpret=interpret,
     )(p2)
-    # take_levels, not out.reshape(-1)[:n]: XLA:CPU miscompiles the fused
-    # stack -> reshape -> odd-slice pattern for some n (see ref.take_levels).
-    return take_levels(out[:, 0, :], out[:, 1, :], n)
+    # (2 * rows, 128) is already in wire order: lo row, then hi row
+    return take_flat(out, n)

@@ -8,6 +8,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.quantizer import levels_of
+
 from . import quantize as _kernel
 from . import ref as _ref
 
@@ -29,7 +31,7 @@ def quantize_dequantize(
     radius: scalar, or theta-shaped for per-element quantization ranges.
     """
     u = jax.random.uniform(key, theta.shape, jnp.float32)
-    levels = (2.0 ** jnp.asarray(bits, jnp.float32)) - 1.0
+    levels = levels_of(bits)
     radius = jnp.asarray(radius, jnp.float32)
     if impl == "ref":
         return _ref.quantize_dequantize_ref(theta, theta_hat_prev, u, radius, levels)

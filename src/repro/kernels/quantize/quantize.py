@@ -2,10 +2,13 @@
 
 The Q-GADMM per-iteration communication hot path touches every parameter:
 read theta and theta_hat_prev, compute level indices with stochastic rounding,
-write the uint8 payload AND the reconstructed theta_hat (sender keeps it so its
-state matches the receiver bit-for-bit).  Unfused, XLA materializes the f32
-intermediates (c, floor, p, compare) in HBM; fused, the op is 3 reads
-(theta, hat, u) + 2 writes (q, hat_new) of which q is 1 byte/elem.
+write the uint8 payload and, in `quantize_dequantize`, the reconstructed
+theta_hat.  Unfused, XLA materializes the f32 intermediates (c, floor, p,
+compare) in HBM; fused, the op is 3 reads (theta, hat, u) + 2 writes
+(q, hat_new) of which q is 1 byte/elem.  `quantize` writes q alone: the
+dist trainer's sender takes its hat from the receivers' decode of q
+(dist.qgadmm._decode), so sender and receiver hats never rest on Mosaic and
+XLA rounding the dequantize alike.
 
 TPU mapping: pure VPU elementwise work tiled in (BLOCK_M, 128) VMEM blocks,
 lane-dim 128-aligned.  Scalars (radius, levels) ride in SMEM via (1,1) blocks.
@@ -19,17 +22,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import check_interpret, take_flat
+
 Array = jax.Array
 
 BLOCK_M = 256  # sublane-dim block; lane dim fixed at 128
 LANES = 128
 
 
-def _qdq_math(radius, levels, theta_ref, hat_ref, u_ref, q_ref, newhat_ref):
+def _qdq_math(radius, levels, theta_ref, hat_ref, u_ref, q_ref, *newhat_ref):
     """Shared kernel body: the scalar-radius and tile-radius variants must
     stay bit-identical (the trainer's cross-impl parity contract), so the
     arithmetic lives in exactly one place.  radius is a scalar or a tile
-    broadcastable against the block."""
+    broadcastable against the block.  newhat_ref is absent in the q-only
+    form (`quantize`)."""
     x = theta_ref[...].astype(jnp.float32)
     h = hat_ref[...].astype(jnp.float32)
     u = u_ref[...]
@@ -40,29 +46,30 @@ def _qdq_math(radius, levels, theta_ref, hat_ref, u_ref, q_ref, newhat_ref):
     p = c - low
     q = low + (u < p).astype(jnp.float32)
     q = jnp.clip(q, 0.0, levels)
-    hat = h + step * q - radius
     active = radius > 0
-    q_ref[...] = jnp.where(active, q, jnp.zeros_like(q)).astype(jnp.uint8)
-    newhat_ref[...] = jnp.where(active, hat, h).astype(newhat_ref.dtype)
+    # Mosaic has no f32 -> u8 cast: convert through int32 (exact, q is an
+    # integer in [0, 255]) and narrow at the store.
+    q_ref[...] = jnp.where(active, q, jnp.zeros_like(q)).astype(
+        jnp.int32).astype(jnp.uint8)
+    for out in newhat_ref:
+        hat = h + step * q - radius
+        out[...] = jnp.where(active, hat, h).astype(out.dtype)
 
 
-def _kernel(r_ref, lv_ref, theta_ref, hat_ref, u_ref, q_ref, newhat_ref):
-    _qdq_math(r_ref[0, 0], lv_ref[0, 0], theta_ref, hat_ref, u_ref, q_ref,
-              newhat_ref)
+def _kernel(r_ref, lv_ref, theta_ref, hat_ref, u_ref, *outs):
+    _qdq_math(r_ref[0, 0], lv_ref[0, 0], theta_ref, hat_ref, u_ref, *outs)
 
 
-def _kernel_vec_r(lv_ref, theta_ref, hat_ref, u_ref, r_ref, q_ref, newhat_ref):
+def _kernel_vec_r(lv_ref, theta_ref, hat_ref, u_ref, r_ref, *outs):
     """Per-element radius variant: R rides in a VMEM tile instead of SMEM.
 
     Used by the dist trainer's per_tensor radius mode, where the per-tensor
     scalars are expanded (segment-scalar gather) into one radius value per
     wire-buffer position."""
-    _qdq_math(r_ref[...], lv_ref[0, 0], theta_ref, hat_ref, u_ref, q_ref,
-              newhat_ref)
+    _qdq_math(r_ref[...], lv_ref[0, 0], theta_ref, hat_ref, u_ref, *outs)
 
 
-def _kernel_vec_rl(theta_ref, hat_ref, u_ref, r_ref, lv_ref, q_ref,
-                   newhat_ref):
+def _kernel_vec_rl(theta_ref, hat_ref, u_ref, r_ref, lv_ref, *outs):
     """Per-element radius AND levels variant: both ride in VMEM tiles.
 
     Used by the dist trainer's layerwise mode, where each leaf owns its own
@@ -70,8 +77,62 @@ def _kernel_vec_rl(theta_ref, hat_ref, u_ref, r_ref, lv_ref, q_ref,
     value per wire-buffer position, same segment-scalar gather as the
     per_tensor radius.  Padding positions carry levels = 1 (never 0: the
     shared math divides by levels) with R = 0 keeping them inert."""
-    _qdq_math(r_ref[...], lv_ref[...], theta_ref, hat_ref, u_ref, q_ref,
-              newhat_ref)
+    _qdq_math(r_ref[...], lv_ref[...], theta_ref, hat_ref, u_ref, *outs)
+
+
+def _fused(theta, theta_hat_prev, u, radius, levels, interpret, with_hat):
+    """The pallas_call behind `quantize_dequantize` (with_hat) and
+    `quantize` (q only): picks the variant from the radius/levels ranks."""
+    check_interpret(interpret)
+    orig_shape = theta.shape
+    n = theta.size
+    cols = LANES
+    rows = -(-n // cols)
+    pad = rows * cols - n
+
+    def to2d(x, fill):
+        flat = x.reshape(-1)
+        if pad:
+            flat = jnp.concatenate([flat, jnp.full((pad,), fill, flat.dtype)])
+        return flat.reshape(rows, cols)
+
+    theta2 = to2d(theta, 0)
+    hat2 = to2d(theta_hat_prev, 0)
+    u2 = to2d(u.astype(jnp.float32), 1.0)  # u=1 never rounds up on padding
+
+    block_m = min(BLOCK_M, rows)
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    tile = pl.BlockSpec((block_m, cols), lambda i: (i, 0))
+    out_shape = [jax.ShapeDtypeStruct((rows, cols), jnp.uint8)]
+    if with_hat:
+        out_shape.append(
+            jax.ShapeDtypeStruct((rows, cols), theta_hat_prev.dtype))
+    if levels.ndim > 0:
+        # layerwise per-element levels: fill padding with 1 (the math
+        # divides by levels), R = 0 keeps those positions inert
+        r_full = (jnp.broadcast_to(radius, theta.shape) if radius.ndim == 0
+                  else radius)
+        kernel, specs = _kernel_vec_rl, [tile] * 5
+        args = (theta2, hat2, u2, to2d(r_full.astype(jnp.float32), 0.0),
+                to2d(levels.astype(jnp.float32), 1.0))
+    elif radius.ndim == 0:
+        kernel, specs = _kernel, [scalar, scalar, tile, tile, tile]
+        args = (radius.astype(jnp.float32).reshape(1, 1),
+                levels.astype(jnp.float32).reshape(1, 1), theta2, hat2, u2)
+    else:
+        # R == 0 on padding: inactive lanes write q = 0, discarded below.
+        kernel, specs = _kernel_vec_r, [scalar] + [tile] * 4
+        args = (levels.astype(jnp.float32).reshape(1, 1), theta2, hat2, u2,
+                to2d(radius.astype(jnp.float32), 0.0))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(-(-rows // block_m),),
+        in_specs=specs,
+        out_specs=[tile] * len(out_shape),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*args)
+    return tuple(take_flat(o, n).reshape(orig_shape) for o in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -92,95 +153,29 @@ def quantize_dequantize(
     `levels` is a scalar (one bit width, SMEM) or an array of theta's shape
     (per-element levels, VMEM tile — the layerwise per-leaf bit widths); the
     per-element-levels path always runs the vec-R kernel (a scalar radius is
-    broadcast).  interpret=True executes the kernel body in Python on CPU
-    (this container); on TPU pass interpret=False.
+    broadcast).  interpret=True executes the kernel body in Python (CPU
+    contract tests; refused on a TPU backend); interpret=False compiles it
+    with Mosaic for the TPU.
     """
-    orig_shape = theta.shape
-    n = theta.size
-    cols = LANES
-    rows = -(-n // cols)
-    pad = rows * cols - n
-
-    def to2d(x, fill):
-        flat = x.reshape(-1)
-        if pad:
-            flat = jnp.concatenate([flat, jnp.full((pad,), fill, flat.dtype)])
-        return flat.reshape(rows, cols)
-
-    theta2 = to2d(theta, 0)
-    hat2 = to2d(theta_hat_prev, 0)
-    u2 = to2d(u.astype(jnp.float32), 1.0)  # u=1 never rounds up on padding
-
-    block_m = min(BLOCK_M, rows)
-    grid = (-(-rows // block_m),)
-
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    tile = pl.BlockSpec((block_m, cols), lambda i: (i, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((rows, cols), jnp.uint8),
-        jax.ShapeDtypeStruct((rows, cols), theta_hat_prev.dtype),
-    ]
-    if levels.ndim > 0:
-        # layerwise per-element levels: fill padding with 1 (the math
-        # divides by levels), R = 0 keeps those positions inert
-        lv2 = to2d(levels.astype(jnp.float32), 1.0)
-        r_full = (jnp.broadcast_to(radius, theta.shape) if radius.ndim == 0
-                  else radius)
-        r2 = to2d(r_full.astype(jnp.float32), 0.0)
-        q2, newhat2 = pl.pallas_call(
-            _kernel_vec_rl,
-            grid=grid,
-            in_specs=[tile, tile, tile, tile, tile],
-            out_specs=[tile, tile],
-            out_shape=out_shape,
-            interpret=interpret,
-        )(theta2, hat2, u2, r2, lv2)
-        q = _take_flat(q2, n).reshape(orig_shape)
-        newhat = _take_flat(newhat2, n).reshape(orig_shape)
-        return q, newhat
-    lv2 = levels.astype(jnp.float32).reshape(1, 1)
-    if radius.ndim == 0:
-        r2 = radius.astype(jnp.float32).reshape(1, 1)
-        q2, newhat2 = pl.pallas_call(
-            _kernel,
-            grid=grid,
-            in_specs=[scalar_spec, scalar_spec, tile, tile, tile],
-            out_specs=[tile, tile],
-            out_shape=out_shape,
-            interpret=interpret,
-        )(r2, lv2, theta2, hat2, u2)
-    else:
-        # R == 0 on padding: inactive lanes write q = 0, discarded below.
-        r2 = to2d(radius.astype(jnp.float32), 0.0)
-        q2, newhat2 = pl.pallas_call(
-            _kernel_vec_r,
-            grid=grid,
-            in_specs=[scalar_spec, tile, tile, tile, tile],
-            out_specs=[tile, tile],
-            out_shape=out_shape,
-            interpret=interpret,
-        )(lv2, theta2, hat2, u2, r2)
-
-    q = _take_flat(q2, n).reshape(orig_shape)
-    newhat = _take_flat(newhat2, n).reshape(orig_shape)
-    return q, newhat
+    return _fused(theta, theta_hat_prev, u, radius, levels, interpret,
+                  with_hat=True)
 
 
-def _take_flat(x2: Array, n: int) -> Array:
-    """First n elements of a (rows, cols) buffer in row-major order.
-
-    Equivalent to x2.reshape(-1)[:n], but slices the row/tail parts before
-    flattening: XLA:CPU miscompiles the fused reshape -> odd-length-slice
-    pattern for some n under SPMD partitioning (same bug family as
-    kernels/pack ref.take_levels)."""
-    rows, cols = x2.shape
-    full = n // cols
-    tail = n - full * cols
-    parts = []
-    if full:
-        parts.append(x2[:full].reshape(-1))
-    if tail:
-        parts.append(x2[full, :tail])
-    if not parts:
-        return jnp.zeros((0,), x2.dtype)
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def quantize(
+    theta: Array,
+    theta_hat_prev: Array,
+    u: Array,
+    radius: Array,
+    levels: Array,
+    *,
+    interpret: bool = True,
+) -> Array:
+    """The levels q of `quantize_dequantize` alone (same variants, same
+    arithmetic): 3 reads and a 1-byte write per element.  The dist
+    trainer's sender uses it and decodes its new hat from q with the
+    receivers' own function, so both ends of an edge agree by
+    construction."""
+    (q,) = _fused(theta, theta_hat_prev, u, radius, levels, interpret,
+                  with_hat=False)
+    return q

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import check_interpret
+
 Array = jax.Array
 
 NEG_INF = float("-inf")
@@ -58,6 +60,7 @@ def ssd_intra(x: Array, dt: Array, la: Array, b: Array, c: Array,
     x: (BC, Q, H, P); dt, la: (BC, Q, H); b, c: (BC, Q, N) — BC = batch*chunks
     flattened, G=1 groups.  Returns (BC, Q, H, P) f32.
     """
+    check_interpret(interpret)
     bc, q, h, p = x.shape
     n = b.shape[-1]
     hb = min(head_block, h)

@@ -178,6 +178,39 @@ def test_quantize_vector_levels_matches_ref(dtype):
                                   np.asarray(hat[600:]))
 
 
+@pytest.mark.parametrize("radius_per_elem,levels_per_elem",
+                         [(False, False), (True, False), (True, True)])
+def test_quantize_q_only_matches_fused(radius_per_elem, levels_per_elem):
+    """`quantize` (the trainer's sender kernel) writes exactly the q of
+    `quantize_dequantize`, in each of the three variants."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(17), 3)
+    n = 700
+    theta = jax.random.normal(k1, (n,))
+    hat = 0.5 * jax.random.normal(k2, (n,))
+    u = jax.random.uniform(k3, (n,), jnp.float32)
+    r = jnp.max(jnp.abs(theta - hat))
+    radius = jnp.full((n,), r).at[600:].set(0.0) if radius_per_elem else r
+    levels = (jnp.where(jnp.arange(n) < 300, 15.0, 3.0) if levels_per_elem
+              else jnp.asarray(255.0))
+    q_fused, _ = q_kernel.quantize_dequantize(theta, hat, u, radius, levels,
+                                              interpret=True)
+    q = q_kernel.quantize(theta, hat, u, radius, levels, interpret=True)
+    assert q.dtype == jnp.uint8 and q.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(q_fused))
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_levels_of_is_exact(traced):
+    """levels_of(b) == 2^b - 1 exactly, for every width the wire carries."""
+    from repro.core.quantizer import levels_of
+    bits = jnp.arange(1, 17, dtype=jnp.int32)
+    lv = jax.jit(levels_of)(bits) if traced else levels_of(bits)
+    assert lv.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(lv),
+                                  2.0 ** np.arange(1, 17) - 1.0)
+    assert float(levels_of(8)) == 255.0
+
+
 SEGS = [  # (sizes, bits) mixed-width framing cases
     ((256,), (4,)),
     ((100, 200), (2, 8)),
