@@ -126,6 +126,20 @@ Array = jax.Array
 
 _ADAM_B1, _ADAM_B2, _ADAM_EPS = 0.9, 0.999, 1e-8
 
+# The layers of one round.  Each wraps its work in the named scope
+# "qgadmm.<layer>", which the compiled step keeps in every instruction's
+# metadata (op_name), so a device trace can be split by layer.  The scopes
+# never nest: every op of a round falls under at most one of them.
+LAYERS = ("local_solve", "codec", "exchange", "decode", "dual", "metrics")
+
+
+def _layer(name: str):
+    """The named scope of one of LAYERS (a context manager and a
+    decorator)."""
+    if name not in LAYERS:
+        raise ValueError(f"unknown layer {name!r}")
+    return jax.named_scope(f"qgadmm.{name}")
+
 
 @dataclasses.dataclass(frozen=True)
 class DistConfig:
@@ -656,6 +670,7 @@ class QGADMMTrainer:
             # tests/test_kernels.py), so the levels move unpacked.
             partner = topo.port  # (W, C) int, -1 where no edge
 
+            @_layer("exchange")
             def exchange(payload):
                 return tuple(
                     jax.tree.map(lambda x: _rows(x, partner[:, c]), payload)
@@ -672,6 +687,7 @@ class QGADMMTrainer:
                 return wire_spec
             return P("worker", *(None,) * (a.ndim - 1))
 
+        @_layer("exchange")
         def exchange(payload):
             specs = jax.tree.map(spec_of, payload)
             # which leaves get per-shard nibble packing (bool leaves: a
@@ -1041,97 +1057,101 @@ class QGADMMTrainer:
         w = self.dcfg.num_workers
         pw = self.pmask if port_weights is None else port_weights
         (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
-        # project the edge slabs to the per-(worker, color) port views the
-        # per-worker local loss is written against (exact; see _port_view)
-        hat_nbr = self._port_view(hat_edge, sharded)
-        lam_nbr = self._port_view(lam_edge, sharded)
-        new_theta, new_mu, new_nu, new_t, f0 = jax.vmap(self._local_opt)(
-            theta, mu, nu, t, batch, lam_nbr, hat_nbr, pw, self.sign)
-        theta = _twhere(active, new_theta, theta)
-        mu = _twhere(active, new_mu, mu)
-        nu = _twhere(active, new_nu, nu)
-        t = jnp.where(active, new_t, t)
+        with _layer("local_solve"):
+            # project the edge slabs to the per-(worker, color) port views
+            # the per-worker local loss is written against (exact; see
+            # _port_view)
+            hat_nbr = self._port_view(hat_edge, sharded)
+            lam_nbr = self._port_view(lam_edge, sharded)
+            new_theta, new_mu, new_nu, new_t, f0 = jax.vmap(self._local_opt)(
+                theta, mu, nu, t, batch, lam_nbr, hat_nbr, pw, self.sign)
+            theta = _twhere(active, new_theta, theta)
+            mu = _twhere(active, new_mu, mu)
+            nu = _twhere(active, new_nu, nu)
+            t = jnp.where(active, new_t, t)
 
-        if g.quantize:
-            q_wire, hat_new, r_new, b_new, leaf_due = self._quantize_all(
-                theta, hat, bits, radius, key, sharded, step_idx)
-            lw = self.dcfg.layerwise
-            if lw is not None:
-                # L-FGADMM leaf gating: a leaf is transmitted only on its
-                # period rounds, and (with per-leaf taus) only when its
-                # committed quantized delta moved past the decaying
-                # threshold.  The candidate hat is the per-leaf mix of
-                # new/old — what would actually be committed — so the
-                # worker-level censor below sees the true delta and both
-                # endpoints stay bit-synced (unsent leaves ride the payload
-                # with radius 0, a codec no-op for every receiver).
-                treedef = jax.tree.structure(hat)
-                hn = treedef.flatten_up_to(hat_new)
-                ho = treedef.flatten_up_to(hat)
-                leaf_sent = leaf_due
-                _, _, taus = self._lw_tables(
-                    tuple(_leaf_sizes(jax.tree.leaves(theta))))
-                if taus is not None:
-                    thr = taus * jnp.power(
-                        jnp.float32(lw.tau_xi),
-                        jnp.asarray(step_idx, jnp.float32))    # (L,)
-                    delta = jnp.sqrt(self._per_leaf_delta2(hn, ho))
-                    leaf_sent = leaf_sent & (delta > thr)
-                hat_cand = jax.tree.unflatten(treedef, [
-                    jnp.where(_bmask(leaf_sent[:, i], a), a, b)
-                    for i, (a, b) in enumerate(zip(hn, ho))])
-                if cc is not None:
-                    sent = active & censor_mod.transmit_mask(
-                        hat_cand, hat, cc, step_idx)
+        with _layer("codec"):
+            if g.quantize:
+                q_wire, hat_new, r_new, b_new, leaf_due = self._quantize_all(
+                    theta, hat, bits, radius, key, sharded, step_idx)
+                lw = self.dcfg.layerwise
+                if lw is not None:
+                    # L-FGADMM leaf gating: a leaf is transmitted only on its
+                    # period rounds, and (with per-leaf taus) only when its
+                    # committed quantized delta moved past the decaying
+                    # threshold.  The candidate hat is the per-leaf mix of
+                    # new/old — what would actually be committed — so the
+                    # worker-level censor below sees the true delta and both
+                    # endpoints stay bit-synced (unsent leaves ride the payload
+                    # with radius 0, a codec no-op for every receiver).
+                    treedef = jax.tree.structure(hat)
+                    hn = treedef.flatten_up_to(hat_new)
+                    ho = treedef.flatten_up_to(hat)
+                    leaf_sent = leaf_due
+                    _, _, taus = self._lw_tables(
+                        tuple(_leaf_sizes(jax.tree.leaves(theta))))
+                    if taus is not None:
+                        thr = taus * jnp.power(
+                            jnp.float32(lw.tau_xi),
+                            jnp.asarray(step_idx, jnp.float32))    # (L,)
+                        delta = jnp.sqrt(self._per_leaf_delta2(hn, ho))
+                        leaf_sent = leaf_sent & (delta > thr)
+                    hat_cand = jax.tree.unflatten(treedef, [
+                        jnp.where(_bmask(leaf_sent[:, i], a), a, b)
+                        for i, (a, b) in enumerate(zip(hn, ho))])
+                    if cc is not None:
+                        sent = active & censor_mod.transmit_mask(
+                            hat_cand, hat, cc, step_idx)
+                    else:
+                        sent = active
+                    eff_leaf = leaf_sent & sent[:, None]           # (W, L)
+                    hat = _twhere(sent, hat_cand, hat)
+                    radius = jnp.where(eff_leaf, r_new, radius)
+                    bits = jnp.where(eff_leaf, b_new, bits)
+                    payload = {"wire": self._finish_wire(q_wire),
+                               "radius": jnp.where(eff_leaf, r_new, 0.0),
+                               "bits": b_new, "sent": sent,
+                               "leaf_sent": eff_leaf}
                 else:
-                    sent = active
-                eff_leaf = leaf_sent & sent[:, None]           # (W, L)
-                hat = _twhere(sent, hat_cand, hat)
-                radius = jnp.where(eff_leaf, r_new, radius)
-                bits = jnp.where(eff_leaf, b_new, bits)
-                payload = {"wire": self._finish_wire(q_wire),
-                           "radius": jnp.where(eff_leaf, r_new, 0.0),
-                           "bits": b_new, "sent": sent,
-                           "leaf_sent": eff_leaf}
+                    if cc is not None:
+                        # CQ-GGADMM censoring: commit + transmit only when
+                        # the quantized model moved past the decaying
+                        # threshold.  hat_new is the committed (per-leaf-cast)
+                        # value, so the mask is identical for every wire_impl
+                        # and on both the unsharded and sharded paths.
+                        sent = active & censor_mod.transmit_mask(
+                            hat_new, hat, cc, step_idx)
+                    else:
+                        sent = active
+                    hat = _twhere(sent, hat_new, hat)
+                    radius = jnp.where(_bmask(sent, r_new), r_new, radius)
+                    bits = jnp.where(sent, b_new, bits)
+                    payload = {"wire": self._finish_wire(q_wire),
+                               "radius": r_new, "bits": b_new, "sent": sent}
             else:
+                # full-precision GADMM: track the would-be radius for metrics,
+                # then "transmit" theta itself (hat == theta).  Censoring
+                # applies identically (this is C-GGADMM).
+                per_leaf_r = self._per_leaf_radius(
+                    jax.tree.leaves(theta), jax.tree.leaves(hat))  # (W, L)
                 if cc is not None:
-                    # CQ-GGADMM censoring: commit + transmit only when the
-                    # quantized model moved past the decaying threshold.
-                    # hat_new is the committed (per-leaf-cast) value, so the
-                    # mask is identical for every wire_impl and on both the
-                    # unsharded and sharded paths.
                     sent = active & censor_mod.transmit_mask(
-                        hat_new, hat, cc, step_idx)
+                        theta, hat, cc, step_idx)
                 else:
                     sent = active
-                hat = _twhere(sent, hat_new, hat)
+                hat = _twhere(sent, theta, hat)
+                r_new = (jnp.max(per_leaf_r, axis=1)
+                         if radius.ndim == 1 and per_leaf_r.shape[1]
+                         else (per_leaf_r if radius.ndim > 1
+                               else jnp.zeros((w,), jnp.float32)))
                 radius = jnp.where(_bmask(sent, r_new), r_new, radius)
-                bits = jnp.where(sent, b_new, bits)
-                payload = {"wire": self._finish_wire(q_wire),
-                           "radius": r_new, "bits": b_new, "sent": sent}
-        else:
-            # full-precision GADMM: track the would-be radius for metrics,
-            # then "transmit" theta itself (hat == theta).  Censoring
-            # applies identically (this is C-GGADMM).
-            per_leaf_r = self._per_leaf_radius(
-                jax.tree.leaves(theta), jax.tree.leaves(hat))  # (W, L)
-            if cc is not None:
-                sent = active & censor_mod.transmit_mask(
-                    theta, hat, cc, step_idx)
-            else:
-                sent = active
-            hat = _twhere(sent, theta, hat)
-            r_new = (jnp.max(per_leaf_r, axis=1)
-                     if radius.ndim == 1 and per_leaf_r.shape[1]
-                     else (per_leaf_r if radius.ndim > 1
-                           else jnp.zeros((w,), jnp.float32)))
-            radius = jnp.where(_bmask(sent, r_new), r_new, radius)
-            payload = {"wire": self._flatten_wire(
-                jax.tree.leaves(hat), jnp.float32), "sent": sent}
+                payload = {"wire": self._flatten_wire(
+                    jax.tree.leaves(hat), jnp.float32), "sent": sent}
 
         return (theta, hat, hat_edge, lam_edge, radius, bits,
                 mu, nu, t), payload, f0
 
+    @_layer("decode")
     def phase_apply(self, st, recv, sharded: bool = False):
         """Fold the exchanged payloads into the edge-indexed neighbor hats.
 
@@ -1177,6 +1197,7 @@ class QGADMMTrainer:
         return (theta, hat, hat_edge, lam_edge, radius, bits,
                 mu, nu, t)
 
+    @_layer("dual")
     def dual_update(self, st, edge_mask=None, sharded: bool = False):
         """Damped dual update (eq. 18) from reconstructed hats; both ends
         of each edge apply the same increment, keeping duals in sync:
@@ -1230,6 +1251,7 @@ class QGADMMTrainer:
 
         port_idx = jnp.asarray(topo.port, jnp.int32) if ports else None
 
+        @_layer("metrics")
         def participation_masks(round_key):
             """Per-round shared-knowledge participation draw: (W,) bool
             mask, degree-renormalized (W, C) port weights, and the (2E,)
@@ -1321,77 +1343,82 @@ class QGADMMTrainer:
                 st = dual_update(st, edge_mask=edge_part)
             (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
 
-            # consensus violation, each edge counted once (from its head:
-            # directed rows whose dst is the head endpoint); gather from a
-            # replicated view — see dual_update's sharded note
-            resid_sq = jnp.zeros(())
-            if self.eidx.num_directed:
-                m = self._d_sign > 0
-                g_hat = self._replicate(hat) if sharded else hat
-                own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), g_hat)
-                resid_sq = resid_sq + sum(jax.tree.leaves(jax.tree.map(
-                    lambda a, b: jnp.sum(_bmask(m, a)
-                                         * (a.astype(jnp.float32)
-                                            - b.astype(jnp.float32)) ** 2),
-                    own, hat_edge)))
-            sent_total = sum(jnp.sum(s.astype(jnp.float32))
-                             for s in sent_phases)
-            metrics = {
-                "loss": jnp.mean(f0),
-                "consensus_resid": jnp.sqrt(resid_sq),
-                "radius_mean": jnp.mean(radius),
-                "bits_mean": jnp.mean(bits.astype(jnp.float32)),
-                # every worker is transmit-eligible exactly once per round
-                "skip_rate": 1.0 - sent_total / w,
-                "wire_bits_per_round": jnp.asarray(
-                    self.wire_bits_per_round(
-                        theta,
-                        sent_phases
-                        if (cc is not None or dcfg.participation < 1.0)
-                        else None,
-                        leaf_phases if dcfg.layerwise is not None else None),
-                    jnp.float32),
-            }
-            if dcfg.telemetry:
-                sp = (sent_phases
-                      if (cc is not None or dcfg.participation < 1.0)
-                      else None)
-                lp = leaf_phases if dcfg.layerwise is not None else None
-                pay, hdr, flg = self.wire_bits_components(theta, sp, lp)
-                deg = jnp.asarray(topo.degree, jnp.float32)
-                sent_any = (sum(s.astype(jnp.float32) for s in sent_phases)
-                            if sent_phases else jnp.zeros((w,), jnp.float32))
-                dual_sq = jnp.zeros(())
+            with _layer("metrics"):
+                # consensus violation, each edge counted once (from its head:
+                # directed rows whose dst is the head endpoint); gather from a
+                # replicated view — see dual_update's sharded note
+                resid_sq = jnp.zeros(())
                 if self.eidx.num_directed:
-                    hm = self._d_sign > 0
-                    dual_sq = dual_sq + sum(jax.tree.leaves(jax.tree.map(
-                        lambda a, b: jnp.sum(
-                            _bmask(hm, a)
-                            * (a.astype(jnp.float32)
-                               - b.astype(jnp.float32)) ** 2),
-                        lam_edge, state.lam_edge)))
-                metrics.update({
-                    "wire_bits_payload": jnp.asarray(pay, jnp.float32),
-                    "wire_bits_header": jnp.asarray(hdr, jnp.float32),
-                    "wire_bits_flags": jnp.asarray(flg, jnp.float32),
-                    # directed links that carried payload / stayed silent
-                    "tx_links": jnp.asarray(
-                        sum(jnp.sum(s.astype(jnp.float32) * deg)
-                            for s in sent_phases), jnp.float32),
-                    "skip_links": jnp.sum((1.0 - sent_any) * deg),
-                    # (W,) per-worker transmit mask: per-edge censor skip
-                    # counts expand host-side via the static edge index
-                    "worker_sent": sent_any,
-                    "dual_resid": jnp.sqrt(dual_sq),
-                    "participants": (jnp.sum(part.astype(jnp.float32))
-                                     if part is not None
-                                     else jnp.asarray(float(w),
-                                                      jnp.float32)),
-                })
-                if dcfg.layerwise is not None:
-                    # (L,) mean allocated bits per leaf across workers
-                    metrics["leaf_bits"] = jnp.mean(
-                        bits.astype(jnp.float32), axis=0)
+                    m = self._d_sign > 0
+                    g_hat = self._replicate(hat) if sharded else hat
+                    own = jax.tree.map(lambda a: _rows(a, self.eidx.dst),
+                                       g_hat)
+                    resid_sq = resid_sq + sum(jax.tree.leaves(jax.tree.map(
+                        lambda a, b: jnp.sum(_bmask(m, a)
+                                             * (a.astype(jnp.float32)
+                                                - b.astype(jnp.float32)) ** 2),
+                        own, hat_edge)))
+                sent_total = sum(jnp.sum(s.astype(jnp.float32))
+                                 for s in sent_phases)
+                metrics = {
+                    "loss": jnp.mean(f0),
+                    "consensus_resid": jnp.sqrt(resid_sq),
+                    "radius_mean": jnp.mean(radius),
+                    "bits_mean": jnp.mean(bits.astype(jnp.float32)),
+                    # every worker is transmit-eligible exactly once per round
+                    "skip_rate": 1.0 - sent_total / w,
+                    "wire_bits_per_round": jnp.asarray(
+                        self.wire_bits_per_round(
+                            theta,
+                            sent_phases
+                            if (cc is not None or dcfg.participation < 1.0)
+                            else None,
+                            leaf_phases if dcfg.layerwise is not None
+                            else None),
+                        jnp.float32),
+                }
+                if dcfg.telemetry:
+                    sp = (sent_phases
+                          if (cc is not None or dcfg.participation < 1.0)
+                          else None)
+                    lp = leaf_phases if dcfg.layerwise is not None else None
+                    pay, hdr, flg = self.wire_bits_components(theta, sp, lp)
+                    deg = jnp.asarray(topo.degree, jnp.float32)
+                    sent_any = (sum(s.astype(jnp.float32)
+                                    for s in sent_phases)
+                                if sent_phases
+                                else jnp.zeros((w,), jnp.float32))
+                    dual_sq = jnp.zeros(())
+                    if self.eidx.num_directed:
+                        hm = self._d_sign > 0
+                        dual_sq = dual_sq + sum(jax.tree.leaves(jax.tree.map(
+                            lambda a, b: jnp.sum(
+                                _bmask(hm, a)
+                                * (a.astype(jnp.float32)
+                                   - b.astype(jnp.float32)) ** 2),
+                            lam_edge, state.lam_edge)))
+                    metrics.update({
+                        "wire_bits_payload": jnp.asarray(pay, jnp.float32),
+                        "wire_bits_header": jnp.asarray(hdr, jnp.float32),
+                        "wire_bits_flags": jnp.asarray(flg, jnp.float32),
+                        # directed links that carried payload / stayed silent
+                        "tx_links": jnp.asarray(
+                            sum(jnp.sum(s.astype(jnp.float32) * deg)
+                                for s in sent_phases), jnp.float32),
+                        "skip_links": jnp.sum((1.0 - sent_any) * deg),
+                        # (W,) per-worker transmit mask: per-edge censor skip
+                        # counts expand host-side via the static edge index
+                        "worker_sent": sent_any,
+                        "dual_resid": jnp.sqrt(dual_sq),
+                        "participants": (jnp.sum(part.astype(jnp.float32))
+                                         if part is not None
+                                         else jnp.asarray(float(w),
+                                                          jnp.float32)),
+                    })
+                    if dcfg.layerwise is not None:
+                        # (L,) mean allocated bits per leaf across workers
+                        metrics["leaf_bits"] = jnp.mean(
+                            bits.astype(jnp.float32), axis=0)
             new_state = DistState(
                 theta=theta, theta_hat=hat, hat_edge=hat_edge,
                 lam_edge=lam_edge, radius=radius, bits=bits,
@@ -1439,21 +1466,22 @@ class QGADMMTrainer:
                                           port_weights=port_weights)
 
         # ---- recv-done: decode the round-(k-S) entry -----------------
-        entry = jax.tree.map(lambda a: a[0], state.inbox)
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
-        if sharded:
-            # same SPMD-partitioner pin as phase_apply(sharded=True)
-            entry, hat_edge, hat_lag = self._replicate(
-                (entry, hat_edge, hat_lag))
-        by_src = jax.tree.map(lambda a: _rows(a, self.eidx.src), entry)
-        hat_edge = self._decode_rows(by_src["wire"], hat_edge,
-                                     by_src["sent"], by_src["radius"],
-                                     by_src["bits"])
-        # own-hat snapshot, decoded from the SAME payload stream the
-        # neighbors decode — hat_lag[w] stays bitwise-equal to every
-        # hat_edge row with src=w, so dual mirrors cannot drift
-        hat_lag = self._decode_rows(entry["wire"], hat_lag, entry["sent"],
-                                    entry["radius"], entry["bits"])
+        with _layer("decode"):
+            entry = jax.tree.map(lambda a: a[0], state.inbox)
+            (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
+            if sharded:
+                # same SPMD-partitioner pin as phase_apply(sharded=True)
+                entry, hat_edge, hat_lag = self._replicate(
+                    (entry, hat_edge, hat_lag))
+            by_src = jax.tree.map(lambda a: _rows(a, self.eidx.src), entry)
+            hat_edge = self._decode_rows(by_src["wire"], hat_edge,
+                                         by_src["sent"], by_src["radius"],
+                                         by_src["bits"])
+            # own-hat snapshot, decoded from the SAME payload stream the
+            # neighbors decode — hat_lag[w] stays bitwise-equal to every
+            # hat_edge row with src=w, so dual mirrors cannot drift
+            hat_lag = self._decode_rows(entry["wire"], hat_lag, entry["sent"],
+                                        entry["radius"], entry["bits"])
         st = (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t)
 
         # ---- compute: both phases against the S-stale hats -----------
@@ -1473,37 +1501,39 @@ class QGADMMTrainer:
         # zero init then, so the gate is belt-and-braces explicitness —
         # the sim's fresh-edge rule promoted to the trainer)
         (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
-        fresh = (state.step >= s_depth).astype(jnp.float32)
-        if self.eidx.num_directed:
-            coef = self._d_sign * fresh
-            if edge_part is not None:
-                coef = coef * edge_part
-            scale = dcfg.gadmm.alpha * dcfg.gadmm.rho
-            own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), hat_lag)
-            lam_edge = jax.tree.map(
-                lambda l, a, b: l + scale * _bmask(coef, l).astype(l.dtype)
-                * (a.astype(l.dtype) - b.astype(l.dtype)),
-                lam_edge, own, hat_edge)
+        with _layer("dual"):
+            fresh = (state.step >= s_depth).astype(jnp.float32)
+            if self.eidx.num_directed:
+                coef = self._d_sign * fresh
+                if edge_part is not None:
+                    coef = coef * edge_part
+                scale = dcfg.gadmm.alpha * dcfg.gadmm.rho
+                own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), hat_lag)
+                lam_edge = jax.tree.map(
+                    lambda l, a, b: l + scale * _bmask(coef, l).astype(l.dtype)
+                    * (a.astype(l.dtype) - b.astype(l.dtype)),
+                    lam_edge, own, hat_edge)
         st = (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t)
 
         # ---- send / recv-start: merge the two phases' payloads (phases
         # partition the workers, so row w comes from exactly one) and
         # push into the ring; the oldest entry just consumed falls out
-        d = sum(_leaf_sizes(jax.tree.leaves(theta)))
-        mix = lambda a, b: jnp.where(_bmask(self.is_head, a), a, b)
-        w_arr = state.inbox["radius"]
-        merged = {
-            "wire": mix(self._strip_wire(pl_h["wire"], d),
-                        self._strip_wire(pl_t["wire"], d)),
-            "radius": (mix(pl_h["radius"], pl_t["radius"])
-                       if "radius" in pl_h else jnp.zeros_like(w_arr[0])),
-            "bits": (mix(pl_h["bits"], pl_t["bits"]) if "bits" in pl_h
-                     else jnp.zeros_like(state.inbox["bits"][0])),
-            "sent": pl_h["sent"] | pl_t["sent"],
-        }
-        inbox = jax.tree.map(
-            lambda buf, new: jnp.concatenate([buf[1:], new[None]], axis=0),
-            state.inbox, merged)
+        with _layer("exchange"):
+            d = sum(_leaf_sizes(jax.tree.leaves(theta)))
+            mix = lambda a, b: jnp.where(_bmask(self.is_head, a), a, b)
+            w_arr = state.inbox["radius"]
+            merged = {
+                "wire": mix(self._strip_wire(pl_h["wire"], d),
+                            self._strip_wire(pl_t["wire"], d)),
+                "radius": (mix(pl_h["radius"], pl_t["radius"])
+                           if "radius" in pl_h else jnp.zeros_like(w_arr[0])),
+                "bits": (mix(pl_h["bits"], pl_t["bits"]) if "bits" in pl_h
+                         else jnp.zeros_like(state.inbox["bits"][0])),
+                "sent": pl_h["sent"] | pl_t["sent"],
+            }
+            inbox = jax.tree.map(
+                lambda buf, new: jnp.concatenate([buf[1:], new[None]], axis=0),
+                state.inbox, merged)
         return st, hat_lag, f0, sent_phases, leaf_phases, inbox
 
     # ------------------------------------------------------- accounting ----

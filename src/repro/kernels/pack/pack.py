@@ -61,6 +61,7 @@ def pack4(q: Array, *, interpret: bool = True) -> Array:
         out_specs=pl.BlockSpec((block_m, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.uint8),
         interpret=interpret,
+        name="pack4",
     )(q2)
     return out.reshape(-1)
 
@@ -80,6 +81,7 @@ def unpack4(packed: Array, n: int, *, interpret: bool = True) -> Array:
         out_specs=pl.BlockSpec((2 * block_m, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((2 * rows, LANES), jnp.uint8),
         interpret=interpret,
+        name="unpack4",
     )(p2)
     # (2 * rows, 128) is already in wire order: lo row, then hi row
     return take_flat(out, n)

@@ -131,6 +131,7 @@ def _fused(theta, theta_hat_prev, u, radius, levels, interpret, with_hat):
         out_specs=[tile] * len(out_shape),
         out_shape=out_shape,
         interpret=interpret,
+        name="quantize_dequantize" if with_hat else "quantize",
     )(*args)
     return tuple(take_flat(o, n).reshape(orig_shape) for o in outs)
 

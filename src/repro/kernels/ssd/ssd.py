@@ -84,5 +84,6 @@ def ssd_intra(x: Array, dt: Array, la: Array, b: Array, c: Array,
         out_specs=pl.BlockSpec((1, q, hb, p), lambda i, j: (i, 0, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bc, q, nhb * hb, p), jnp.float32),
         interpret=interpret,
+        name="ssd_intra",
     )(x, dt, la, b, c)
     return out[:, :, :h]

@@ -99,7 +99,13 @@ def parse_args(argv=None) -> argparse.Namespace:
 def enable_compile_cache() -> str:
     """Persistent compile cache: JAX reads $JAX_COMPILATION_CACHE_DIR itself
     when it is set; otherwise the cache lives at the fixed <repo>/.jax_cache
-    (the path is part of the cache key, so it never moves)."""
+    (the path is part of the cache key, so it never moves).
+
+    The key covers the program's metadata too: the round's layer scopes
+    (dist.qgadmm.LAYERS) live only there, and a program that differs from a
+    cached one in its metadata alone would otherwise load with the cached
+    compile's op names, in its HLO text and in every profile of it."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(CACHE_DIR)

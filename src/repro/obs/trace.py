@@ -30,7 +30,7 @@ _US = 1e6   # trace timestamps are microseconds
 
 # ------------------------------------------------------------ TraceWriter ---
 class TraceWriter:
-    """Wall-clock span/instant recorder for host-side phases."""
+    """Wall-clock span recorder for host-side phases."""
 
     def __init__(self) -> None:
         self.events: list[dict] = []
@@ -61,11 +61,6 @@ class TraceWriter:
                                 "tid": tid, "ts": ts,
                                 "dur": self._now_us() - ts,
                                 "args": args or {}})
-
-    def instant(self, name: str, tid: int = 0, **args) -> None:
-        self.events.append({"name": name, "ph": "i", "s": "t", "pid": 1,
-                            "tid": tid, "ts": self._now_us(),
-                            "args": args or {}})
 
     def write(self, path: str) -> None:
         write_trace(path, self.events)

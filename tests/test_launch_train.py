@@ -29,16 +29,20 @@ def _python(code: str, env_extra=None, timeout: int = 300) -> str:
 
 def test_train_colocated_workers_on_one_device(tmp_path):
     """One device, W=2: the single-device step with all workers on it,
-    invariants checked, compile cache in $JAX_COMPILATION_CACHE_DIR."""
+    invariants checked, compile cache in $JAX_COMPILATION_CACHE_DIR, keyed
+    by the program's metadata (the layer scopes) too."""
     out = _python(f"""
         import jax
         from repro.launch import train
         train.main({SMOKE + ["--workers", "2", "--steps", "4"]!r})
         print("cache_dir", jax.config.jax_compilation_cache_dir)
+        print("keyed by metadata",
+              jax.config.jax_compilation_cache_include_metadata_in_key)
         """, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
     assert "mesh: {'worker': 1, 'fsdp': 1, 'model': 1}" in out, out
     assert "REPRO_CHECK: wire accounting + edge mirrors OK" in out, out
     assert f"cache_dir {tmp_path}" in out, out
+    assert "keyed by metadata True" in out, out
     assert any(tmp_path.iterdir()), "no compiled program was cached"
 
 
