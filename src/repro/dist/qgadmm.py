@@ -411,6 +411,19 @@ def _rows(x: Array, idx, sliced: bool = True) -> Array:
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
+def _put_rows(x: Array, idx, vals: Array) -> Array:
+    """x with its rows idx (static, ascending) replaced by vals: the inverse
+    of _rows(x, idx), one static-offset update per run of consecutive
+    rows (no scatter)."""
+    idx = np.asarray(idx)
+    runs = np.split(np.arange(idx.size), np.flatnonzero(np.diff(idx) != 1) + 1)
+    for r in runs:
+        if r.size:
+            x = jax.lax.dynamic_update_slice_in_dim(
+                x, vals[r[0]:r[-1] + 1], int(idx[r[0]]), axis=0)
+    return x
+
+
 def _decode(q: Array, hat_f: Array, radius: Array, levels: Array) -> Array:
     """The dequantize arithmetic of the wire (f32): levels q against the
     previous hat, R == 0 a no-op.  The one definition shared by the sender
@@ -499,17 +512,19 @@ class QGADMMTrainer:
             tree)
 
     # ------------------------------------------------------------ views ----
-    def _port_view(self, slab, sharded: bool = False):
+    def _port_view(self, slab, sharded: bool = False, rows=None):
         """Edge-slab pytree (2E, ...) -> tuple over edge colors of stacked
         (W, ...) trees (the port-dense layout the per-worker local loss is
         written against).  Exact: active rows are the stored slab rows,
         missing ports read as the zeros those rows always held in the
         port-dense layout.  In the sharded step the view is sharded over
         'worker', so it is a gather there (see _rows: static slices took
-        the W=16 chain step from 6 s to 608 s to compile on XLA:CPU)."""
+        the W=16 chain step from 6 s to 608 s to compile on XLA:CPU).
+        rows: a static worker subset; the view then has one row each."""
+        slot = self.eidx.slot if rows is None else self.eidx.slot[rows]
         return tuple(
-            jax.tree.map(lambda s: _rows(s, self.eidx.slot[:, c],
-                                         sliced=not sharded), slab)
+            jax.tree.map(lambda s: _rows(s, slot[:, c], sliced=not sharded),
+                         slab)
             for c in range(self.topo.num_ports))
 
     def port_views(self, state: DistState) -> dict:
@@ -1032,7 +1047,7 @@ class QGADMMTrainer:
                        in_shardings=(ss, bs), out_shardings=(ss, None))
 
     def phase_compute(self, st, batch, active, key, step_idx,
-                      sharded: bool = False, port_weights=None):
+                      sharded: bool = False, port_weights=None, rows=None):
         """Local Adam + quantize (+ censor) for the active workers;
         returns the updated state and the wire payload (exchange NOT yet
         applied).  payload['sent'] is the per-worker transmit flag — the
@@ -1051,24 +1066,43 @@ class QGADMMTrainer:
         Worker row w of every output depends only on row w of the inputs
         (plus the shared uniform-draw key), so a single worker can replay
         its own row from a local view whose other rows are garbage — the
-        contract repro.sim.worker.TrainerActor builds on."""
+        contract repro.sim.worker.TrainerActor builds on.
+
+        `rows` (static indices, ascending) runs the local solve on those
+        workers alone and commits only their rows: `active` is cut to
+        them, every other row of the state is returned as it came in and
+        sends nothing, and f0 is 0 there.  None solves every row (the
+        simulator's dynamic masks need that).  The codec still codes all
+        W rows: its uniform draw spans (W, D)."""
         g = self.dcfg.gadmm
         cc = self.dcfg.censor
         w = self.dcfg.num_workers
         pw = self.pmask if port_weights is None else port_weights
         (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
+        if rows is None:
+            sub = lambda tree: tree
+            put = lambda full, part: part
+        else:
+            rows = np.asarray(rows)
+            active = active & jnp.asarray(np.isin(np.arange(w), rows))
+            sub = lambda tree: jax.tree.map(lambda a: _rows(a, rows), tree)
+            put = lambda full, part: jax.tree.map(
+                lambda a, b: _put_rows(a, rows, b), full, part)
         with _layer("local_solve"):
             # project the edge slabs to the per-(worker, color) port views
             # the per-worker local loss is written against (exact; see
             # _port_view)
-            hat_nbr = self._port_view(hat_edge, sharded)
-            lam_nbr = self._port_view(lam_edge, sharded)
+            hat_nbr = self._port_view(hat_edge, sharded, rows)
+            lam_nbr = self._port_view(lam_edge, sharded, rows)
+            act = sub(active)
+            old = sub((theta, mu, nu, t))
             new_theta, new_mu, new_nu, new_t, f0 = jax.vmap(self._local_opt)(
-                theta, mu, nu, t, batch, lam_nbr, hat_nbr, pw, self.sign)
-            theta = _twhere(active, new_theta, theta)
-            mu = _twhere(active, new_mu, mu)
-            nu = _twhere(active, new_nu, nu)
-            t = jnp.where(active, new_t, t)
+                *old, sub(batch), lam_nbr, hat_nbr, sub(pw), sub(self.sign))
+            theta = put(theta, _twhere(act, new_theta, old[0]))
+            mu = put(mu, _twhere(act, new_mu, old[1]))
+            nu = put(nu, _twhere(act, new_nu, old[2]))
+            t = put(t, jnp.where(act, new_t, old[3]))
+            f0 = put(jnp.zeros((w,), f0.dtype), f0)
 
         with _layer("codec"):
             if g.quantize:
@@ -1231,6 +1265,34 @@ class QGADMMTrainer:
         return (theta, hat, hat_edge, lam_edge, radius, bits,
                 mu, nu, t)
 
+    def _groups(self, sharded: bool):
+        """Static (heads, tails) rows each Gauss-Seidel phase solves, or
+        (None, None) to solve every row in both.  Where all workers share
+        one device a phase solves its own group alone, so each worker
+        runs one local solve a round.  On a worker-sharded mesh every
+        chip solves its row in both phases: the idle phase costs the same
+        wall time, and a row subset would move rows between chips."""
+        heads = np.flatnonzero(self.topo.head_mask)
+        tails = np.flatnonzero(~self.topo.head_mask)
+        if sharded or not (heads.size and tails.size):
+            return None, None
+        return heads, tails
+
+    def local_solves(self, sharded: bool) -> int:
+        """Worker local solves one round runs: W where each worker is
+        solved once (Jacobi; Gauss-Seidel with the workers on one
+        device), 2W where every row is solved in both phases."""
+        w = self.dcfg.num_workers
+        stale = self.dcfg.staleness > 0 and w > 1 and self.topo.num_edges > 0
+        two_phase = stale or (self.dcfg.mode == "gauss-seidel" and w > 1)
+        return 2 * w if two_phase and self._groups(sharded)[0] is None else w
+
+    def _merge_f0(self, f0_h, f0_t, rows_h):
+        """The round's start-of-round data losses: all of phase 1's when it
+        solved every row, else each group's from its own phase (a tail's
+        theta and batch are the same at the start of both phases)."""
+        return f0_h if rows_h is None else jnp.where(self.is_head, f0_h, f0_t)
+
     def _build_step(self, sharded: bool):
         dcfg = self.dcfg
         g = dcfg.gadmm
@@ -1248,6 +1310,7 @@ class QGADMMTrainer:
         phase_compute = functools.partial(self.phase_compute, sharded=sharded)
         phase_apply = functools.partial(self.phase_apply, sharded=sharded)
         dual_update = functools.partial(self.dual_update, sharded=sharded)
+        rows_h, rows_t = self._groups(sharded)
 
         port_idx = jnp.asarray(topo.port, jnp.int32) if ports else None
 
@@ -1287,9 +1350,10 @@ class QGADMMTrainer:
                 part, pw, edge_part = participation_masks(state.key)
             mask = (lambda a: a) if part is None else (lambda a: a & part)
 
-            def phase(st, active, k):
+            def phase(st, active, k, rows=None):
                 st, payload, f0 = phase_compute(st, batch, mask(active), k,
-                                                state.step, port_weights=pw)
+                                                state.step, port_weights=pw,
+                                                rows=rows)
                 sent_phases.append(payload["sent"])
                 lf = payload.pop("leaf_sent", None)
                 if lf is not None:
@@ -1318,15 +1382,18 @@ class QGADMMTrainer:
                 # exchanges in.  XLA sees no data dependence between the
                 # heads' ppermute and the tails' compute, so the graph
                 # latency hides behind the Adam iterations.
-                st, pl_h, f0 = phase_compute(st, batch, mask(is_head), k1,
-                                             state.step, port_weights=pw)
+                st, pl_h, f0_h = phase_compute(st, batch, mask(is_head), k1,
+                                               state.step, port_weights=pw,
+                                               rows=rows_h)
                 sent_phases.append(pl_h["sent"])
                 lf = pl_h.pop("leaf_sent", None)
                 if lf is not None:
                     leaf_phases.append((lf, pl_h["bits"]))
                 recv_h = exchange(pl_h)
-                st, pl_t, _ = phase_compute(st, batch, mask(~is_head), k2,
-                                            state.step, port_weights=pw)
+                st, pl_t, f0_t = phase_compute(st, batch, mask(~is_head), k2,
+                                               state.step, port_weights=pw,
+                                               rows=rows_t)
+                f0 = self._merge_f0(f0_h, f0_t, rows_h)
                 sent_phases.append(pl_t["sent"])
                 lf = pl_t.pop("leaf_sent", None)
                 if lf is not None:
@@ -1335,8 +1402,9 @@ class QGADMMTrainer:
                 st = phase_apply(st, exchange(pl_t))
                 st = dual_update(st, edge_mask=edge_part)
             elif dcfg.mode == "gauss-seidel" and w > 1:
-                st, f0 = phase(st, is_head, k1)
-                st, _ = phase(st, ~is_head, k2)
+                st, f0_h = phase(st, is_head, k1, rows_h)
+                st, f0_t = phase(st, ~is_head, k2, rows_t)
+                f0 = self._merge_f0(f0_h, f0_t, rows_h)
                 st = dual_update(st, edge_mask=edge_part)
             else:
                 st, f0 = phase(st, all_on, k1)
@@ -1487,8 +1555,12 @@ class QGADMMTrainer:
         # ---- compute: both phases against the S-stale hats -----------
         act_h = self.is_head if part is None else self.is_head & part
         act_t = ~self.is_head if part is None else ~self.is_head & part
-        st, pl_h, f0 = phase_compute(st, batch, act_h, k1, state.step)
-        st, pl_t, _ = phase_compute(st, batch, act_t, k2, state.step)
+        rows_h, rows_t = self._groups(sharded)
+        st, pl_h, f0_h = phase_compute(st, batch, act_h, k1, state.step,
+                                       rows=rows_h)
+        st, pl_t, f0_t = phase_compute(st, batch, act_t, k2, state.step,
+                                       rows=rows_t)
+        f0 = self._merge_f0(f0_h, f0_t, rows_h)
         sent_phases = [pl_h["sent"], pl_t["sent"]]
         leaf_phases = []
         for pl in (pl_h, pl_t):

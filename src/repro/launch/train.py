@@ -214,7 +214,8 @@ def build(args: argparse.Namespace, **dist_overrides) -> Run:
                        dcfg)
     batch0 = next_batch()
     state, _ = trainer.place(state, batch0)
-    if wmesh.shape["worker"] == args.workers:
+    sharded = wmesh.shape["worker"] == args.workers
+    if sharded:
         step = trainer.jit_train_step(state, batch0)
     else:   # all workers co-located on the one device
         step = jax.jit(trainer.make_train_step(), donate_argnums=0)
@@ -233,7 +234,8 @@ def build(args: argparse.Namespace, **dist_overrides) -> Run:
     manifest = record.manifest_record(
         dcfg, seed=0, topology=args.topology, num_workers=args.workers,
         extra={"cli": "launch.train", "arch": args.arch,
-               "steps": args.steps, "mesh": dict(wmesh.shape)})
+               "steps": args.steps, "mesh": dict(wmesh.shape),
+               "local_solves": trainer.local_solves(sharded)})
     mlog = record.MetricsLog(path=args.metrics_out, manifest=manifest,
                              log_every=args.log_every)
     return Run(trainer=trainer, state=state, step_fn=step_fn,

@@ -2,6 +2,7 @@
 
 The launcher runs in subprocesses (it sets the compile cache and, with
 --devices, the platform and device count, which are process-wide)."""
+import json
 import os
 import subprocess
 import sys
@@ -27,14 +28,24 @@ def _python(code: str, env_extra=None, timeout: int = 300) -> str:
     return r.stdout
 
 
+def _manifest(path) -> dict:
+    with open(path) as f:
+        rec = json.loads(f.readline())
+    assert rec["kind"] == "manifest"
+    return rec
+
+
 def test_train_colocated_workers_on_one_device(tmp_path):
     """One device, W=2: the single-device step with all workers on it,
     invariants checked, compile cache in $JAX_COMPILATION_CACHE_DIR, keyed
-    by the program's metadata (the layer scopes) too."""
+    by the program's metadata (the layer scopes) too; the run record's
+    manifest counts one local solve per worker a round."""
+    rec = tmp_path / "run.jsonl"
     out = _python(f"""
         import jax
         from repro.launch import train
-        train.main({SMOKE + ["--workers", "2", "--steps", "4"]!r})
+        train.main({SMOKE + ["--workers", "2", "--steps", "4",
+                             "--metrics-out", str(rec)]!r})
         print("cache_dir", jax.config.jax_compilation_cache_dir)
         print("keyed by metadata",
               jax.config.jax_compilation_cache_include_metadata_in_key)
@@ -43,17 +54,22 @@ def test_train_colocated_workers_on_one_device(tmp_path):
     assert "REPRO_CHECK: wire accounting + edge mirrors OK" in out, out
     assert f"cache_dir {tmp_path}" in out, out
     assert "keyed by metadata True" in out, out
-    assert any(tmp_path.iterdir()), "no compiled program was cached"
+    assert any(p != rec for p in tmp_path.iterdir()), \
+        "no compiled program was cached"
+    assert _manifest(rec)["local_solves"] == 2
 
 
-def test_train_emulated_mesh_one_worker_per_device():
+def test_train_emulated_mesh_one_worker_per_device(tmp_path):
     """--devices 4, W=4 at 4 bits: the sharded step over a (4, 1, 1) mesh
-    with the nibble-packed wire; the cache falls back to <repo>/.jax_cache."""
+    with the nibble-packed wire; the cache falls back to <repo>/.jax_cache;
+    every chip solves its worker in both Gauss-Seidel phases."""
+    rec = tmp_path / "run.jsonl"
     out = _python(f"""
         import jax
         from repro.launch import train
         train.main({SMOKE + ["--devices", "4", "--workers", "4", "--bits",
-                             "4", "--steps", "2"]!r})
+                             "4", "--steps", "2",
+                             "--metrics-out", str(rec)]!r})
         print("cache_dir", jax.config.jax_compilation_cache_dir)
         print("devices", len(jax.devices()), jax.default_backend())
         """, {"JAX_COMPILATION_CACHE_DIR": ""})
@@ -62,6 +78,7 @@ def test_train_emulated_mesh_one_worker_per_device():
     assert "devices 4 cpu" in out, out
     repo = os.path.abspath(os.path.join(SRC, ".."))
     assert f"cache_dir {os.path.join(repo, '.jax_cache')}" in out, out
+    assert _manifest(rec)["local_solves"] == 8
 
 
 def test_worker_mesh_from_present_devices():
