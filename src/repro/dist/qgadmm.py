@@ -57,25 +57,26 @@ one jax.lax.ppermute permutation — the collective schedule is the
 canonical core.topology.edge_schedule, derived from the graph, never
 hard-coded +-1 shifts.
 
-State layout (O(C) -> O(E)).  Neighbor state is EDGE-INDEXED: the
-topology's 2E directed edges (core.topology.edge_index, sorted by
-(dst, src)) each own one slab row, so `DistState.hat_edge[d]` is what
-worker dst(d) knows about src(d)'s hat and `lam_edge[d]` is dst(d)'s
-mirror of the shared edge dual (canonical head -> tail orientation; both
-directions of an edge hold bitwise-equal mirrors in lockstep).  The old
-port-dense layout kept C = max-degree full (W, ...) tuples — O(W*C*D)
-memory and per-step dequantize/dual work even at degree 1; the slabs are
-O(E*D), and `edge_index.slot` projects them back to per-(worker, color)
-port views wherever the math is per-worker (the local loss) or the
-transport is per-color (the sharded ppermute exchange).  The projection
-is exact: gathered rows are the stored rows, missing ports read as the
-zeros they always were.
+State layout.  Neighbor state is OWNER-INDEXED: `DistState.hat_nbr` and
+`lam_nbr` leaves are (W * C, ...) with C = the topology's number of edge
+colors, slot (w, c) at row w * C + c, sharded like theta ('worker' on dim
+0, so each worker's C rows sit on its own devices).  Slot (w, c) is what
+worker w knows about its color-c partner's hat and w's mirror of that
+edge's dual (canonical head -> tail orientation; both endpoints hold
+bitwise-equal mirrors in lockstep); slots of absent ports hold zeros and
+are masked.  Each chip therefore holds, decodes and dual-updates only
+its own workers' slots: slot (w, c) is fed by recv[c][w], exactly what
+the color-c ppermute delivers to w, so the decode and the dual update
+are elementwise over worker-sharded operands.  `DistState.hat_edge` /
+`lam_edge` read the same state as the topology's 2E directed rows
+(core.topology.edge_index order, row d = slot (dst(d), color(d))).
 
 `DistConfig.staleness = S > 0` replaces the per-color exchange barrier
 with an explicit send / recv-start / recv-done pipeline: each round's
 merged head+tail payload is SENT into an S-deep in-flight ring buffer
-(`DistState.inbox` — recv-start), and the round-(k-S) entry is decoded
-into the edge slabs at the top of round k (recv-done), so every worker
+(`DistState.inbox` — recv-start), and the round-(k-S) entry is moved by
+the per-color exchange and decoded into the slots at the top of round k
+(recv-done), so every worker
 computes against neighbor hats that are exactly S rounds stale.  Duals
 update against the matching S-stale snapshot of the worker's OWN hat
 (`DistState.hat_lag`, decoded from the same payload stream), so both
@@ -302,15 +303,29 @@ class DistState(NamedTuple):
     stacked with a leading (num_workers,) dim sharded over the mesh
     'worker' axis.
 
-    Neighbor state is EDGE-INDEXED (O(E), not O(W*C)): the topology's 2E
-    directed edges (core.topology.edge_index, sorted by (dst, src)) each
-    own one slab row.  ``hat_edge`` leaf rows are what dst(d) knows about
-    src(d)'s hat; ``lam_edge`` rows are dst(d)'s mirror of the shared edge
-    dual (canonical head -> tail orientation — in lockstep both directions
-    of an edge are bitwise-equal).  ``edge_index.slot[w, c]`` projects a
-    slab back to the per-(worker, edge-color) port view where needed.  A
-    chain has 2E = 2(W-1) rows, a star 2(W-1), a 2d-torus 4W — always
-    2E = sum of degrees, never W * max-degree.
+    Neighbor state is OWNER-INDEXED: ``hat_nbr``/``lam_nbr`` leaves are
+    (W * C, ...), C = the topology's edge colors (core.topology
+    ``num_ports``), slot (w, c) at row w * C + c, sharded like ``theta``
+    (the W*C rows in W blocks of C).  Slot (w, c) holds what w
+    knows about its color-c partner's hat and w's mirror of the shared
+    edge dual (canonical head -> tail orientation — in lockstep both
+    endpoints are bitwise-equal); absent ports hold zeros.  A worker's
+    slots live on its own chip, next to its theta.
+
+    Cost: the layout holds W * C rows where the directed edges number 2E
+    = sum of degrees.  A chain or ring (C = 2) holds 2W against 2(W-1) or
+    2W; a star (hub degree W-1, so C = W-1) holds W(W-1) against 2(W-1),
+    every leaf's slots padded to the hub's degree.
+
+    ``hat_edge``/``lam_edge`` read the slots as the 2E directed-edge rows
+    in core.topology.edge_index order (sorted by (dst, src)): row d is
+    slot (dst(d), color(d)), whose row ``edge_slot[d]`` = dst(d) * C +
+    color(d) the state carries, so a reader needs no trainer.
+
+    The slot dim is merged into the leading dim, not a dim of its own:
+    with C = 1 (a W=2 chain) the leaves are (W, ...) like theta's, and
+    the one-chip program keeps theta's layouts (a separate unit dim cost
+    XLA:TPU 93 more layout copies a round at whisper-tiny).
 
     ``inbox``/``hat_lag`` exist only at staleness S > 0: the S-deep ring of
     in-flight payload rounds ({wire, radius, bits, sent} stacked with a
@@ -320,8 +335,8 @@ class DistState(NamedTuple):
 
     theta: Any      # current primal parameters
     theta_hat: Any  # own last-quantized model (== what neighbors hold)
-    hat_edge: Any   # directed-edge slab (2E, ...): dst's view of src's hat
-    lam_edge: Any   # directed-edge slab (2E, ...): dst's dual mirror
+    hat_nbr: Any    # owner slots (W * C, ...): w's copy of its partner's hat
+    lam_nbr: Any    # owner slots (W * C, ...): w's mirror of the edge dual
     radius: Array   # (W,) global mode | (W, n_tensors) per_tensor mode
     bits: Array     # (W,) int32 | (W, n_tensors) layerwise mode
     opt_mu: Any     # local Adam first moment
@@ -329,8 +344,19 @@ class DistState(NamedTuple):
     opt_t: Array    # (W,) int32 Adam step counts
     key: Array      # PRNG key (stochastic rounding)
     step: Array     # () int32
+    edge_slot: Array  # (2E,) int32: slot row dst * C + color of each edge
     inbox: Any = () # staleness > 0: S-deep in-flight payload ring
     hat_lag: Any = ()  # staleness > 0: own hat, S rounds delayed
+
+    @property
+    def hat_edge(self):
+        """(2E, ...) directed-edge rows: dst(d)'s copy of src(d)'s hat."""
+        return _edge_rows(self.hat_nbr, self.edge_slot)
+
+    @property
+    def lam_edge(self):
+        """(2E, ...) directed-edge rows: dst(d)'s mirror of the edge dual."""
+        return _edge_rows(self.lam_nbr, self.edge_slot)
 
 
 def init_state(init_fn: Callable[[Array], Any], key: Array,
@@ -357,9 +383,10 @@ def init_state(init_fn: Callable[[Array], Any], key: Array,
         bits0 = jnp.tile(jnp.asarray(lw_bits, jnp.int32)[None], (w, 1))
     else:
         bits0 = jnp.full((w,), dcfg.gadmm.qcfg.bits, jnp.int32)
-    de = 2 * topo.num_edges
-    edge_zeros = lambda: jax.tree.map(
-        lambda a: jnp.zeros((de,) + a.shape, a.dtype), params)
+    c = topo.num_ports
+    slot_zeros = lambda: jax.tree.map(
+        lambda a: jnp.zeros((w * c,) + a.shape, a.dtype), params)
+    eidx = edge_index(topo)
     inbox, hat_lag = (), ()
     if dcfg.staleness > 0:
         s = dcfg.staleness
@@ -376,11 +403,12 @@ def init_state(init_fn: Callable[[Array], Any], key: Array,
         hat_lag = zeros()
     return DistState(
         theta=theta, theta_hat=zeros(),
-        hat_edge=edge_zeros(), lam_edge=edge_zeros(),
+        hat_nbr=slot_zeros(), lam_nbr=slot_zeros(),
         radius=radius, bits=bits0,
         opt_mu=zeros(), opt_nu=zeros(),
         opt_t=jnp.zeros((w,), jnp.int32),
         key=k_state, step=jnp.zeros((), jnp.int32),
+        edge_slot=jnp.asarray(eidx.dst * c + eidx.color, jnp.int32),
         inbox=inbox, hat_lag=hat_lag)
 
 
@@ -389,26 +417,36 @@ def _bmask(m: Array, leaf: Array) -> Array:
     return m.reshape(m.shape + (1,) * (leaf.ndim - m.ndim))
 
 
-def _rows(x: Array, idx, sliced: bool = True) -> Array:
+def _rows(x: Array, idx) -> Array:
     """x[idx] along axis 0 for a static index vector; -1 selects a zero row.
-
-    sliced: built from static slices, not a gather — XLA:TPU takes minutes
-    to compile a gather whose slices are whole rows of a wide wire buffer or
-    slab.  Where the result is sharded over its rows, the SPMD partitioner
-    expands each slice on its own and the HLO grows as O(W^2), so such a
-    caller asks for the one gather (sliced=False)."""
+    Built from static slices, not a gather — XLA:TPU takes minutes to
+    compile a gather whose slices are whole rows of a wide wire buffer."""
     idx = np.asarray(idx)
-    if not sliced:
-        out = x[jnp.asarray(np.maximum(idx, 0), jnp.int32)]
-        if (idx < 0).any():
-            out = jnp.where(_bmask(jnp.asarray(idx >= 0), out), out,
-                            jnp.zeros_like(out))
-        return out
     parts = [jnp.zeros_like(x[:1]) if i < 0 else x[i:i + 1]
              for i in idx.tolist()]
     if not parts:
         return x[:0]
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+@jax.jit
+def _take_rows(slots, edge_slot):
+    return jax.tree.map(lambda a: jnp.take(a, edge_slot, axis=0), slots)
+
+
+def _edge_rows(slots, edge_slot):
+    """Owner slots (W * C, ...) -> the (2E, ...) directed-edge rows, row d
+    from slot edge_slot[d]: one gather a leaf, or the slots themselves (no
+    copy) where every slot is an edge row in edge order (a W=2 chain).  A
+    gather, not static slices: for a worker-sharded state XLA:TPU compiles
+    it in seconds (whisper-tiny on v5e:2x2: 5 s against 18 s, and 54 MB of
+    temporaries against 3.0 GB a chip)."""
+    if not isinstance(edge_slot, jax.core.Tracer):
+        idx = np.asarray(edge_slot)
+        if ({a.shape[0] for a in jax.tree.leaves(slots)} == {idx.size}
+                and (idx == np.arange(idx.size)).all()):
+            return slots
+    return _take_rows(slots, edge_slot)
 
 
 def _put_rows(x: Array, idx, vals: Array) -> Array:
@@ -477,11 +515,13 @@ class QGADMMTrainer:
         self.pmask = jnp.asarray(pmask_np, jnp.float32)
         self.is_head = jnp.asarray(self.topo.head_mask)
         self.sign = jnp.where(self.is_head, 1.0, -1.0).astype(jnp.float32)
-        # Directed-edge tables for the O(E) neighbor-state slabs.
+        # Directed-edge tables (the simulator's and the readers' edge
+        # order) and each owner slot's dual sign: +1 a head's present
+        # port, -1 a tail's, 0 an absent port.
         self.eidx = edge_index(self.topo)
         self._d_src = jnp.asarray(self.eidx.src, jnp.int32)    # (2E,)
         self._d_dst = jnp.asarray(self.eidx.dst, jnp.int32)    # (2E,)
-        self._d_sign = jnp.asarray(self.eidx.sign_dst)         # (2E,) f32
+        self._slot_sign = (self.sign[:, None] * self.pmask).reshape(-1)
         # layerwise: per-leaf tables cache + the per-leaf eq. 11 config
         self._lw_cache: dict = {}
         lw = dcfg.layerwise
@@ -502,37 +542,41 @@ class QGADMMTrainer:
                 None if taus is None else jnp.asarray(taus, jnp.float32))
         return self._lw_cache[sizes]
 
-    def _replicate(self, tree):
-        """Pin every leaf of a pytree to the fully replicated layout (a
-        with_sharding_constraint; only meaningful inside the sharded jit)."""
-        from jax.sharding import NamedSharding
+    def _edge_to_slots(self, x: Array) -> Array:
+        """(2E,) per-directed-edge values -> (W * C,) owner slots (0 where
+        a port is absent)."""
+        return _rows(x, self.eidx.slot.reshape(-1))
+
+    def _per_slot(self, tree):
+        """(W, ...) per-worker rows -> (W * C, ...): row w repeated for
+        each of its C slots (local to the worker's chip)."""
+        w, c = self.dcfg.num_workers, self.topo.num_ports
         return jax.tree.map(
-            lambda x: jax.lax.with_sharding_constraint(
-                x, NamedSharding(self.mesh, P(*(None,) * jnp.ndim(x)))),
-            tree)
+            lambda a: jnp.broadcast_to(a[:, None], (w, c) + a.shape[1:])
+            .reshape((w * c,) + a.shape[1:]), tree)
 
     # ------------------------------------------------------------ views ----
-    def _port_view(self, slab, sharded: bool = False, rows=None):
-        """Edge-slab pytree (2E, ...) -> tuple over edge colors of stacked
-        (W, ...) trees (the port-dense layout the per-worker local loss is
-        written against).  Exact: active rows are the stored slab rows,
-        missing ports read as the zeros those rows always held in the
-        port-dense layout.  In the sharded step the view is sharded over
-        'worker', so it is a gather there (see _rows: static slices took
-        the W=16 chain step from 6 s to 608 s to compile on XLA:CPU).
-        rows: a static worker subset; the view then has one row each."""
-        slot = self.eidx.slot if rows is None else self.eidx.slot[rows]
-        return tuple(
-            jax.tree.map(lambda s: _rows(s, slot[:, c], sliced=not sharded),
-                         slab)
-            for c in range(self.topo.num_ports))
+    def _port_view(self, slots, rows=None):
+        """Owner slots (W * C, ...) -> tuple over edge colors of stacked
+        (W, ...) trees, the per-port form the local loss is written
+        against: a reshape to (W, C, ...) and a static slice along the
+        slot dim, local to each worker's chip.  rows: a static worker
+        subset; the view then has one row each."""
+        w, ports = self.dcfg.num_workers, self.topo.num_ports
+
+        def view(a, c):
+            v = a.reshape((w, ports) + a.shape[1:])[:, c]
+            return v if rows is None else _rows(v, rows)
+
+        return tuple(jax.tree.map(lambda a: view(a, c), slots)
+                     for c in range(ports))
 
     def port_views(self, state: DistState) -> dict:
-        """Public projection of the edge-indexed neighbor state back to the
-        pre-refactor per-(worker, color) port views — the layout-independent
-        surface the golden replay tier and the sim parity tests compare."""
-        return {"hat_nbr": self._port_view(state.hat_edge),
-                "lam_nbr": self._port_view(state.lam_edge)}
+        """The neighbor state as per-(worker, color) port views, tuples over
+        colors of (W, ...) trees — the layout-independent surface the
+        golden replay tier and the sim parity tests compare."""
+        return {"hat_nbr": self._port_view(state.hat_nbr),
+                "lam_nbr": self._port_view(state.lam_nbr)}
 
     # ------------------------------------------------------------ specs ----
     def batch_specs(self, batch):
@@ -550,8 +594,6 @@ class QGADMMTrainer:
         au = self.dcfg.uneven_shard
         pspec = functools.partial(sh.tree_specs, leaf_rule=sh.leaf_train_spec,
                                   mesh=self.mesh, allow_uneven=au)
-        espec = functools.partial(sh.tree_specs, leaf_rule=sh.leaf_edge_spec,
-                                  mesh=self.mesh, allow_uneven=au)
         wspec = P("worker") if self.dcfg.num_workers > 1 else P(None)
         inbox, hat_lag = (), ()
         if self.dcfg.staleness > 0:
@@ -566,12 +608,14 @@ class QGADMMTrainer:
             hat_lag = pspec(state.hat_lag)
         return DistState(
             theta=pspec(state.theta), theta_hat=pspec(state.theta_hat),
-            hat_edge=espec(state.hat_edge), lam_edge=espec(state.lam_edge),
+            # owner slots: W blocks of C rows, each on its worker's devices
+            hat_nbr=pspec(state.hat_nbr), lam_nbr=pspec(state.lam_nbr),
             radius=(wspec if state.radius.ndim == 1
                     else P(*wspec, None)),
             bits=(wspec if state.bits.ndim == 1 else P(*wspec, None)),
             opt_mu=pspec(state.opt_mu), opt_nu=pspec(state.opt_nu),
-            opt_t=wspec, key=P(None), step=P(), inbox=inbox, hat_lag=hat_lag)
+            opt_t=wspec, key=P(None), step=P(), edge_slot=P(None),
+            inbox=inbox, hat_lag=hat_lag)
 
     def _shardings(self, specs):
         return sh.tree_shardings(specs, self.mesh)
@@ -594,8 +638,8 @@ class QGADMMTrainer:
     def _flatten_rows(self, leaves, dtype):
         """[(R, ...)] -> one (R, D) buffer (zero-size leaves contribute 0
         columns).  R is whatever leading dim the leaves carry — the worker
-        count on the stacked wire path, a per-color edge-row count on the
-        slab decode path."""
+        count on the stacked wire path, the W * C flattened owner slots on
+        the decode path."""
         rows = leaves[0].shape[0] if leaves else self.dcfg.num_workers
         cols = [l.reshape(rows, -1).astype(dtype) for l in leaves]
         if not cols:
@@ -805,7 +849,7 @@ class QGADMMTrainer:
         a gather whose output is sharded along the gathered dimension,
         which XLA:CPU miscompiles inside the fused step — the sender
         quantized against garbage radii while receivers (whose decode runs
-        on replicated operands, see phase_apply) used the true ones, so
+        outside the shard_map, see _decode_slots) used the true ones, so
         every sharded per_tensor/layerwise run silently desynced and the
         consensus residual grew without bound."""
         wspec = P("worker") if self.dcfg.num_workers > 1 else P(None)
@@ -1078,7 +1122,7 @@ class QGADMMTrainer:
         cc = self.dcfg.censor
         w = self.dcfg.num_workers
         pw = self.pmask if port_weights is None else port_weights
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
+        (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = st
         if rows is None:
             sub = lambda tree: tree
             put = lambda full, part: part
@@ -1089,15 +1133,11 @@ class QGADMMTrainer:
             put = lambda full, part: jax.tree.map(
                 lambda a, b: _put_rows(a, rows, b), full, part)
         with _layer("local_solve"):
-            # project the edge slabs to the per-(worker, color) port views
-            # the per-worker local loss is written against (exact; see
-            # _port_view)
-            hat_nbr = self._port_view(hat_edge, sharded, rows)
-            lam_nbr = self._port_view(lam_edge, sharded, rows)
             act = sub(active)
             old = sub((theta, mu, nu, t))
             new_theta, new_mu, new_nu, new_t, f0 = jax.vmap(self._local_opt)(
-                *old, sub(batch), lam_nbr, hat_nbr, sub(pw), sub(self.sign))
+                *old, sub(batch), self._port_view(lam_nbr, rows),
+                self._port_view(hat_nbr, rows), sub(pw), sub(self.sign))
             theta = put(theta, _twhere(act, new_theta, old[0]))
             mu = put(mu, _twhere(act, new_mu, old[1]))
             nu = put(nu, _twhere(act, new_nu, old[2]))
@@ -1182,88 +1222,68 @@ class QGADMMTrainer:
                 payload = {"wire": self._flatten_wire(
                     jax.tree.leaves(hat), jnp.float32), "sent": sent}
 
-        return (theta, hat, hat_edge, lam_edge, radius, bits,
+        return (theta, hat, hat_nbr, lam_nbr, radius, bits,
                 mu, nu, t), payload, f0
 
+    def _decode_slots(self, recv, slots):
+        """Decode the per-color exchanged payloads into the owner slots:
+        slot (w, c) takes recv[c][w], the payload w received from its
+        color-c partner.  The C colors stack along the slot dim, so the
+        decode is elementwise over worker-sharded operands: each chip
+        decodes its own workers' C slots, with no gather.  A partner that
+        stayed silent (censored, inactive, or no color-c edge: the
+        exchange's zero fill) leaves the slot as it was."""
+        n = self.dcfg.num_workers * self.topo.num_ports
+        col = lambda k: jnp.stack([r[k] for r in recv], axis=1).reshape(
+            (n,) + recv[0][k].shape[1:])
+        d = sum(int(np.prod(l.shape[1:])) for l in jax.tree.leaves(slots))
+        side = ((col("radius"), col("bits")) if self.dcfg.gadmm.quantize
+                else (None, None))
+        return self._decode_rows(self._strip_wire(col("wire"), d), slots,
+                                 col("sent"), *side)
+
     @_layer("decode")
-    def phase_apply(self, st, recv, sharded: bool = False):
-        """Fold the exchanged payloads into the edge-indexed neighbor hats.
-
-        recv[c]['sent'][w] is the exchanged censor flag: did w's color-c
-        partner transmit?  Censored (or phase-inactive) partners leave
+    def phase_apply(self, st, recv):
+        """Fold the exchanged payloads into the owner-indexed neighbor hats
+        (`_decode_slots`).  Censored (or phase-inactive) partners leave
         the stored hat untouched — exactly what their own rolled-back
-        state holds, preserving bit-sync.  Directed row d is served by
-        the payload worker dst[d] received on port color[d], so the whole
-        slab commits as ONE uniform row selection + decode over the 2E
-        rows — one decode per directed edge, O(E) work instead of the
-        port-dense O(W*C).
-
-        The full-slab form is deliberate: an earlier per-color version
-        (static row-subset gather, decode, ``.at[rows].set`` scatter)
-        was miscompiled by XLA:CPU's SPMD partitioner inside the fused
-        sharded step — O(1) absolute garbage in the committed rows once
-        the slab was nonzero (same bug family as the RoPE and
-        in-shard-codec notes; sharding pins on the operands did NOT fix
-        the fused program).  The uniform full-slab form avoids the
-        scatter entirely.  sharded=True additionally pins the decode's
-        operands replicated — the slabs are O(E*D) and every worker
-        stores them anyway, so that is the intended semantics, not a
-        workaround cost."""
-        g = self.dcfg.gadmm
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
+        state holds, preserving bit-sync."""
+        (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = st
         if self.eidx.num_directed == 0:
             return st
-        if sharded:
-            recv, hat_edge = self._replicate((recv, hat_edge))
+        hat_nbr = self._decode_slots(recv, hat_nbr)
+        return (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t)
 
-        def pick(k):
-            # per-color (W, ...) payloads -> per-directed-row (2E, ...)
-            return jnp.concatenate([
-                recv[c][k][w:w + 1]
-                for c, w in zip(self.eidx.color.tolist(),
-                                self.eidx.dst.tolist())])
-
-        d = sum(_leaf_sizes(jax.tree.leaves(theta)))
-        side = ((pick("radius"), pick("bits")) if g.quantize
-                else (None, None))
-        hat_edge = self._decode_rows(self._strip_wire(pick("wire"), d),
-                                     hat_edge, pick("sent"), *side)
-        return (theta, hat, hat_edge, lam_edge, radius, bits,
-                mu, nu, t)
+    def _dual_step(self, lam_nbr, own, hat_nbr, coef):
+        """lam[w, c] += alpha * rho * coef[w, c] * (own[w] - hat[w, c]):
+        elementwise over the owner slots, own repeated over each worker's
+        slots."""
+        scale = self.dcfg.gadmm.alpha * self.dcfg.gadmm.rho
+        return jax.tree.map(
+            lambda l, a, b: l + scale * _bmask(coef, l).astype(l.dtype)
+            * (a.astype(l.dtype) - b.astype(l.dtype)),
+            lam_nbr, self._per_slot(own), hat_nbr)
 
     @_layer("dual")
-    def dual_update(self, st, edge_mask=None, sharded: bool = False):
+    def dual_update(self, st, edge_mask=None):
         """Damped dual update (eq. 18) from reconstructed hats; both ends
         of each edge apply the same increment, keeping duals in sync:
         lam_e += a*rho*(hat_head - hat_tail), which the head computes
-        as +(own - nbr) and the tail as -(own - nbr) — per directed edge
-        d that is sign_dst[d] * (hat[dst[d]] - hat_edge[d]).
+        as +(own - nbr) and the tail as -(own - nbr) — per owner slot
+        (w, c) that is sign[w] * (hat[w] - hat_nbr[w, c]), each chip on
+        its own workers' slots.
 
-        `edge_mask` (2E,) zeroes selected directed edges — the simulator
-        masks edges whose far endpoint dropped (freezing those duals
-        instead of integrating a stale residual forever), the staleness
-        pipeline masks everything during fill rounds.
-
-        sharded=True pins the worker-stacked hats replicated before the
-        (2E,)-row gather: leaving the gather on the worker-sharded
-        layout makes XLA:CPU's SPMD partitioner corrupt OTHER values in
-        the fused step (the committed hat_edge rows — the gather's mere
-        presence flips the partitioning of the decode upstream)."""
-        g = self.dcfg.gadmm
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
+        `edge_mask` (2E,), in edge_index order, zeroes selected directed
+        edges — the simulator masks edges whose far endpoint dropped
+        (freezing those duals instead of integrating a stale residual
+        forever), partial participation edges with an absent endpoint."""
+        (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = st
         if self.eidx.num_directed == 0:
             return st
-        coef = (self._d_sign if edge_mask is None
-                else self._d_sign * edge_mask)   # (2E,) f32
-        scale = g.alpha * g.rho
-        g_hat = self._replicate(hat) if sharded else hat
-        own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), g_hat)
-        lam_edge = jax.tree.map(
-            lambda l, a, b: l + scale * _bmask(coef, l).astype(l.dtype)
-            * (a.astype(l.dtype) - b.astype(l.dtype)),
-            lam_edge, own, hat_edge)
-        return (theta, hat, hat_edge, lam_edge, radius, bits,
-                mu, nu, t)
+        coef = (self._slot_sign if edge_mask is None
+                else self._slot_sign * self._edge_to_slots(edge_mask))
+        lam_nbr = self._dual_step(lam_nbr, hat, hat_nbr, coef)
+        return (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t)
 
     def _groups(self, sharded: bool):
         """Static (heads, tails) rows each Gauss-Seidel phase solves, or
@@ -1308,8 +1328,6 @@ class QGADMMTrainer:
         all_on = jnp.ones((w,), bool)
         exchange = (self._make_exchange(sharded) if topo.num_edges else None)
         phase_compute = functools.partial(self.phase_compute, sharded=sharded)
-        phase_apply = functools.partial(self.phase_apply, sharded=sharded)
-        dual_update = functools.partial(self.dual_update, sharded=sharded)
         rows_h, rows_t = self._groups(sharded)
 
         port_idx = jnp.asarray(topo.port, jnp.int32) if ports else None
@@ -1339,8 +1357,8 @@ class QGADMMTrainer:
 
         def step(state: DistState, batch):
             key, k1, k2 = jax.random.split(state.key, 3)
-            st = (state.theta, state.theta_hat, state.hat_edge,
-                  state.lam_edge, state.radius, state.bits, state.opt_mu,
+            st = (state.theta, state.theta_hat, state.hat_nbr,
+                  state.lam_nbr, state.radius, state.bits, state.opt_mu,
                   state.opt_nu, state.opt_t)
             sent_phases = []
             leaf_phases = []   # layerwise: (eff_leaf, bits) per phase
@@ -1359,7 +1377,7 @@ class QGADMMTrainer:
                 if lf is not None:
                     leaf_phases.append((lf, payload["bits"]))
                 if exchange is not None:
-                    st = phase_apply(st, exchange(payload))
+                    st = self.phase_apply(st, exchange(payload))
                 return st, f0
 
             stale = (dcfg.staleness > 0 and w > 1 and topo.num_edges > 0)
@@ -1373,7 +1391,7 @@ class QGADMMTrainer:
                 # eventually consumed.
                 (st, hat_lag, f0, sent_phases, leaf_phases,
                  inbox) = self._stale_round(
-                    st, batch, state, hat_lag, k1, k2, sharded,
+                    st, batch, state, hat_lag, k1, k2, sharded, exchange,
                     part=part, port_weights=pw, edge_part=edge_part)
             elif dcfg.mode == "gauss-seidel" and w > 1 and dcfg.overlap:
                 # double-buffered exchange: put the heads' payload on the
@@ -1398,34 +1416,31 @@ class QGADMMTrainer:
                 lf = pl_t.pop("leaf_sent", None)
                 if lf is not None:
                     leaf_phases.append((lf, pl_t["bits"]))
-                st = phase_apply(st, recv_h)
-                st = phase_apply(st, exchange(pl_t))
-                st = dual_update(st, edge_mask=edge_part)
+                st = self.phase_apply(st, recv_h)
+                st = self.phase_apply(st, exchange(pl_t))
+                st = self.dual_update(st, edge_mask=edge_part)
             elif dcfg.mode == "gauss-seidel" and w > 1:
                 st, f0_h = phase(st, is_head, k1, rows_h)
                 st, f0_t = phase(st, ~is_head, k2, rows_t)
                 f0 = self._merge_f0(f0_h, f0_t, rows_h)
-                st = dual_update(st, edge_mask=edge_part)
+                st = self.dual_update(st, edge_mask=edge_part)
             else:
                 st, f0 = phase(st, all_on, k1)
-                st = dual_update(st, edge_mask=edge_part)
-            (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
+                st = self.dual_update(st, edge_mask=edge_part)
+            (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = st
 
             with _layer("metrics"):
-                # consensus violation, each edge counted once (from its head:
-                # directed rows whose dst is the head endpoint); gather from a
-                # replicated view — see dual_update's sharded note
+                # consensus violation, each edge counted once (from its
+                # head's slot): each chip sums its own workers' slots, only
+                # the scalar is reduced across chips
                 resid_sq = jnp.zeros(())
+                head_slot = self._slot_sign > 0                  # (W * C,)
                 if self.eidx.num_directed:
-                    m = self._d_sign > 0
-                    g_hat = self._replicate(hat) if sharded else hat
-                    own = jax.tree.map(lambda a: _rows(a, self.eidx.dst),
-                                       g_hat)
                     resid_sq = resid_sq + sum(jax.tree.leaves(jax.tree.map(
-                        lambda a, b: jnp.sum(_bmask(m, a)
+                        lambda a, b: jnp.sum(_bmask(head_slot, b)
                                              * (a.astype(jnp.float32)
                                                 - b.astype(jnp.float32)) ** 2),
-                        own, hat_edge)))
+                        self._per_slot(hat), hat_nbr)))
                 sent_total = sum(jnp.sum(s.astype(jnp.float32))
                                  for s in sent_phases)
                 metrics = {
@@ -1458,13 +1473,12 @@ class QGADMMTrainer:
                                 else jnp.zeros((w,), jnp.float32))
                     dual_sq = jnp.zeros(())
                     if self.eidx.num_directed:
-                        hm = self._d_sign > 0
                         dual_sq = dual_sq + sum(jax.tree.leaves(jax.tree.map(
                             lambda a, b: jnp.sum(
-                                _bmask(hm, a)
+                                _bmask(head_slot, a)
                                 * (a.astype(jnp.float32)
                                    - b.astype(jnp.float32)) ** 2),
-                            lam_edge, state.lam_edge)))
+                            lam_nbr, state.lam_nbr)))
                     metrics.update({
                         "wire_bits_payload": jnp.asarray(pay, jnp.float32),
                         "wire_bits_header": jnp.asarray(hdr, jnp.float32),
@@ -1488,10 +1502,10 @@ class QGADMMTrainer:
                         metrics["leaf_bits"] = jnp.mean(
                             bits.astype(jnp.float32), axis=0)
             new_state = DistState(
-                theta=theta, theta_hat=hat, hat_edge=hat_edge,
-                lam_edge=lam_edge, radius=radius, bits=bits,
+                theta=theta, theta_hat=hat, hat_nbr=hat_nbr,
+                lam_nbr=lam_nbr, radius=radius, bits=bits,
                 opt_mu=mu, opt_nu=nu, opt_t=t, key=key, step=state.step + 1,
-                inbox=inbox, hat_lag=hat_lag)
+                edge_slot=state.edge_slot, inbox=inbox, hat_lag=hat_lag)
             return new_state, metrics
 
         return step
@@ -1500,12 +1514,12 @@ class QGADMMTrainer:
     def _decode_rows(self, wire, prev, sent, radius=None, bits=None):
         """Decode stripped wire rows against stored prev rows; rows whose
         sender stayed silent (sent False) keep prev.  The shared decode of
-        the barriered exchange (neighbor slab rows) and of the staleness
-        pipeline's recv-done (slab rows and the own-hat lag), so a
-        pipeline replays the exact bytes the S=0 exchange would.
+        the neighbor slots (`_decode_slots`, barriered or at the staleness
+        pipeline's recv-done) and of the own-hat lag, so a pipeline replays
+        the exact bytes the S=0 exchange would.
 
         On the quantized wire a silent row decodes with R = 0, the codec's
-        no-op, which returns prev bitwise: no select over the whole slabs,
+        no-op, which returns prev bitwise: no select over the whole state,
         which XLA:TPU compiles very slowly."""
         if self.dcfg.gadmm.quantize:
             return self._dequantize_all(
@@ -1518,11 +1532,13 @@ class QGADMMTrainer:
             treedef, [l.astype(r.dtype) for l, r in zip(ls, leaves)]), prev)
 
     def _stale_round(self, st, batch, state: DistState, hat_lag, k1, k2,
-                     sharded: bool, part=None, port_weights=None,
+                     sharded: bool, exchange, part=None, port_weights=None,
                      edge_part=None):
-        """One staleness-S round: recv-done on the oldest inbox entry, both
-        compute phases against the S-stale hats, fresh-edge-gated dual
-        update on matching S-stale snapshots, send into the ring.  With
+        """One staleness-S round: recv-done on the oldest inbox entry (the
+        per-color exchange moves it to the partners, who decode it into
+        their slots), both compute phases against the S-stale hats,
+        fresh-edge-gated dual update on matching S-stale snapshots, send
+        into the ring.  With
         partial participation the round's shared mask gates the compute
         phases (`part`), reweights the neighbor sums (`port_weights`) and
         joins the fresh-edge gate on the dual (`edge_part`) — absent
@@ -1534,23 +1550,17 @@ class QGADMMTrainer:
                                           port_weights=port_weights)
 
         # ---- recv-done: decode the round-(k-S) entry -----------------
+        entry = jax.tree.map(lambda a: a[0], state.inbox)
+        recv = exchange(entry)
         with _layer("decode"):
-            entry = jax.tree.map(lambda a: a[0], state.inbox)
-            (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
-            if sharded:
-                # same SPMD-partitioner pin as phase_apply(sharded=True)
-                entry, hat_edge, hat_lag = self._replicate(
-                    (entry, hat_edge, hat_lag))
-            by_src = jax.tree.map(lambda a: _rows(a, self.eidx.src), entry)
-            hat_edge = self._decode_rows(by_src["wire"], hat_edge,
-                                         by_src["sent"], by_src["radius"],
-                                         by_src["bits"])
+            (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = st
+            hat_nbr = self._decode_slots(recv, hat_nbr)
             # own-hat snapshot, decoded from the SAME payload stream the
             # neighbors decode — hat_lag[w] stays bitwise-equal to every
-            # hat_edge row with src=w, so dual mirrors cannot drift
+            # partner's slot holding w, so dual mirrors cannot drift
             hat_lag = self._decode_rows(entry["wire"], hat_lag, entry["sent"],
                                         entry["radius"], entry["bits"])
-        st = (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t)
+        st = (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t)
 
         # ---- compute: both phases against the S-stale hats -----------
         act_h = self.is_head if part is None else self.is_head & part
@@ -1572,20 +1582,14 @@ class QGADMMTrainer:
         # during the S pipeline-fill rounds (both sides are still the
         # zero init then, so the gate is belt-and-braces explicitness —
         # the sim's fresh-edge rule promoted to the trainer)
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
+        (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = st
         with _layer("dual"):
             fresh = (state.step >= s_depth).astype(jnp.float32)
-            if self.eidx.num_directed:
-                coef = self._d_sign * fresh
-                if edge_part is not None:
-                    coef = coef * edge_part
-                scale = dcfg.gadmm.alpha * dcfg.gadmm.rho
-                own = jax.tree.map(lambda a: _rows(a, self.eidx.dst), hat_lag)
-                lam_edge = jax.tree.map(
-                    lambda l, a, b: l + scale * _bmask(coef, l).astype(l.dtype)
-                    * (a.astype(l.dtype) - b.astype(l.dtype)),
-                    lam_edge, own, hat_edge)
-        st = (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t)
+            coef = self._slot_sign * fresh
+            if edge_part is not None:
+                coef = coef * self._edge_to_slots(edge_part)
+            lam_nbr = self._dual_step(lam_nbr, hat_lag, hat_nbr, coef)
+        st = (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t)
 
         # ---- send / recv-start: merge the two phases' payloads (phases
         # partition the workers, so row w comes from exactly one) and

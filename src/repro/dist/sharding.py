@@ -72,7 +72,9 @@ def _candidate_dims(shape, start: int, leaf_key: str):
 def leaf_train_spec(shape, mesh: Mesh, allow_uneven: bool = False,
                     leaf_key: str = "") -> P:
     """Spec for one stacked trainer leaf (W, ...): worker on dim 0, the
-    largest remaining dim FSDP-sharded, the next largest tensor-parallel."""
+    largest remaining dim FSDP-sharded, the next largest tensor-parallel.
+    The trainer's owner slots (W * C, ...) take it too: 'worker' splits
+    their rows into W blocks of C, one per worker."""
     if len(shape) == 0:
         return P()
     order = _candidate_dims(shape, 1, leaf_key)
@@ -82,22 +84,6 @@ def leaf_train_spec(shape, mesh: Mesh, allow_uneven: bool = False,
         for i in order[1:]:
             rules.append((i, ("model",)))
     return _assign(shape, rules, mesh, allow_uneven=allow_uneven)
-
-
-def leaf_edge_spec(shape, mesh: Mesh, allow_uneven: bool = False,
-                   leaf_key: str = "") -> P:
-    """Spec for one directed-edge slab leaf (2E, ...) of the trainer's
-    edge-indexed neighbor state: fully replicated.  The leading edge dim
-    cannot ride the worker axis (a worker's incident edge count is its
-    degree — ragged), and sharding the inner model dims trips the same
-    XLA:CPU SPMD partitioner miscompile documented for the in-shard codec:
-    the row-subset gather/scatter decode that commits received rows into
-    the slab produces O(1) garbage when the slab output is repartitioned
-    to a model-sharded layout at the step boundary.  The decode therefore
-    pins its operands replicated, and the slab spec must agree so the step
-    output is not resharded back through the broken partition path."""
-    del allow_uneven, leaf_key  # replicated regardless of shape
-    return P(*(None,) * len(shape))
 
 
 def leaf_serve_spec(shape, mesh: Mesh, allow_uneven: bool = False,

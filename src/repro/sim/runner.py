@@ -339,14 +339,14 @@ def _trainer_fns(trainer):
                 jnp.zeros(()), jnp.zeros((), jnp.int32))
 
     @jax.jit
-    def apply(st, d, row):
-        """Store the partner's committed hat row at directed slab row d
-        (the value the reference's in-program phase_apply reconstructs
+    def apply(st, s, row):
+        """Store the partner's committed hat row at owner slot row s (the
+        value the reference's in-program phase_apply reconstructs
         bit-identically; see TrainerActor._phase)."""
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = st
-        hat_edge = jax.tree.map(lambda a, r: a.at[d].set(r.astype(a.dtype)),
-                                hat_edge, row)
-        return (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t)
+        (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = st
+        hat_nbr = jax.tree.map(
+            lambda a, r: a.at[s].set(r.astype(a.dtype)), hat_nbr, row)
+        return (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t)
 
     @jax.jit
     def dual(st, edge_mask):
@@ -394,7 +394,7 @@ def simulate_trainer(trainer, state0, batch, scfg: SimConfig,
             for l in jax.tree.leaves(state0.theta))
     fns = _trainer_fns(trainer)
     keys = _beacon(state0.key, scfg.rounds)
-    st0 = (state0.theta, state0.theta_hat, state0.hat_edge, state0.lam_edge,
+    st0 = (state0.theta, state0.theta_hat, state0.hat_nbr, state0.lam_nbr,
            state0.radius, state0.bits, state0.opt_mu, state0.opt_nu,
            state0.opt_t)
 

@@ -47,9 +47,9 @@ neighbor's reconstructed row, and the round-k dual step uses the
 *common round* k-S snapshot of both endpoints (the completion gate
 guarantees round k-S is applied).  This is exactly the
 ``DistConfig.staleness`` pipeline's dual rule (dist.qgadmm._stale_round:
-``hat_lag`` vs the S-stale slab), so a latency-bound async run and the
-trainer's in-step pipeline share a fixed point.  S=0 keeps the original
-fresh-edge mask path bit-for-bit.
+``hat_lag`` vs the S-stale neighbor slots), so a latency-bound async run
+and the trainer's in-step pipeline share a fixed point.  S=0 keeps the
+original fresh-edge mask path bit-for-bit.
 """
 from __future__ import annotations
 
@@ -418,13 +418,16 @@ class TrainerActor(BaseActor):
         self.quantize = trainer.dcfg.gadmm.quantize
         self.active = jnp.asarray(topo.head_mask if self.is_head
                                   else ~topo.head_mask)
-        # neighbor j -> the directed slab row with dst=i that stores what i
-        # knows about j (the trainer's edge-indexed state layout)
+        # neighbor j -> the directed edge (j, i), whose dual-mask entry
+        # gates i's slot for j, and that slot's row i * C + color in the
+        # trainer's owner-indexed state: what i knows about j
         self.eidx = trainer.eidx
         self._in_edge = self.eidx.in_edges(self.i)
+        self._slot = {j: self.i * topo.num_ports + int(self.eidx.color[d])
+                      for j, d in self._in_edge.items()}
         self.edge_alive = np.ones((self.eidx.num_directed,), np.float32)
         # S-deep lag histories for the async common-round dual (module
-        # docstring): round -> committed own hat row / reconstructed slab row
+        # docstring): round -> committed own hat row / reconstructed slot
         self._own_hist: dict[int, Any] = {}
         self._nbr_hist: dict[int, dict[int, Any]] = \
             {j: {} for j in self.neighbors}
@@ -450,21 +453,19 @@ class TrainerActor(BaseActor):
         return True, body, self.payload_bits
 
     def _apply(self, j, msg):
-        self.st = self.fns["apply"](
-            self.st, jnp.asarray(self._in_edge[j], jnp.int32),
-            msg.body["hat"])
+        self.st = self.fns["apply"](self.st, self._slot[j], msg.body["hat"])
 
     def _post_advance(self, j, rnd):
         if self.staleness > 0:
-            d = self._in_edge[j]
-            self._nbr_hist[j][rnd] = jax.tree.map(lambda a: a[d],
+            s = self._slot[j]
+            self._nbr_hist[j][rnd] = jax.tree.map(lambda a: a[s],
                                                   self.st[2])
 
     def _dual_update(self):
         mask = self.edge_alive.copy()
         if self.staleness == 0:
             # same fresh-edge gating as GraphActor._edge_mask, on the
-            # directed slab rows with dst=i (the other rows of the local
+            # directed edges with dst=i (the other slots of the local
             # view are don't-care)
             for j, d in self._in_edge.items():
                 if j not in self.dead and self.nbr_round[j] < self.rnd:
@@ -472,25 +473,25 @@ class TrainerActor(BaseActor):
             self.st = self.fns["dual"](self.st, jnp.asarray(mask))
             return
         # async: splice the round-(k-S) common snapshot (own hat row +
-        # in-edge slab rows) into a scratch state, take the dual step
-        # there, and keep only its lam_edge — this is the trainer
-        # pipeline's `hat_lag` dual rule (dist.qgadmm._stale_round)
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = self.st
+        # in-edge slots) into a scratch state, take the dual step there,
+        # and keep only its lam_nbr — this is the trainer pipeline's
+        # `hat_lag` dual rule (dist.qgadmm._stale_round)
+        (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = self.st
         self._own_hist[self.rnd] = jax.tree.map(lambda a: a[self.i], hat)
         lag = self.rnd - self.staleness
         if lag >= 0:
             hat_sub = _set_row(hat, self.i, self._own_hist[lag])
-            hat_edge_sub = hat_edge
+            hat_nbr_sub = hat_nbr
             for j, d in self._in_edge.items():
                 row = self._nbr_hist[j].get(lag)
                 if row is None:        # dead before round `lag` — frozen
                     mask[d] = 0.0
                 else:
-                    hat_edge_sub = _set_row(hat_edge_sub, d, row)
-            st_sub = (theta, hat_sub, hat_edge_sub, lam_edge, radius,
+                    hat_nbr_sub = _set_row(hat_nbr_sub, self._slot[j], row)
+            st_sub = (theta, hat_sub, hat_nbr_sub, lam_nbr, radius,
                       bits, mu, nu, t)
-            lam_edge = self.fns["dual"](st_sub, jnp.asarray(mask))[3]
-            self.st = (theta, hat, hat_edge, lam_edge, radius, bits,
+            lam_nbr = self.fns["dual"](st_sub, jnp.asarray(mask))[3]
+            self.st = (theta, hat, hat_nbr, lam_nbr, radius, bits,
                        mu, nu, t)
         for h in (self._own_hist, *self._nbr_hist.values()):
             for r in [r for r in h if r < self.rnd - self.staleness]:
@@ -502,24 +503,18 @@ class TrainerActor(BaseActor):
 
     def _snapshot(self):
         import jax
-        (theta, hat, hat_edge, lam_edge, radius, bits, mu, nu, t) = self.st
+        (theta, hat, hat_nbr, lam_nbr, radius, bits, mu, nu, t) = self.st
         row = lambda tree: jax.tree.map(
             lambda a: np.asarray(a[self.i]), tree)
-
-        def port_row(slab, c):
-            # slab row with dst=i and color c, or the zeros a missing port
-            # always held in the port-dense layout
-            s = int(self.eidx.slot[self.i, c])
-            if s < 0:
-                return jax.tree.map(
-                    lambda a: np.asarray(jnp.zeros_like(a[0])), slab)
-            return jax.tree.map(lambda a: np.asarray(a[s]), slab)
-
+        # slot (i, c): zeros where i has no color-c port
         ports = self.topo.num_ports
+        port_row = lambda slots, c: jax.tree.map(
+            lambda a: np.asarray(a[self.i * ports + c]), slots)
+
         return dict(theta=row(theta), hat=row(hat),
-                    hat_nbr=tuple(port_row(hat_edge, c)
+                    hat_nbr=tuple(port_row(hat_nbr, c)
                                   for c in range(ports)),
-                    lam_nbr=tuple(port_row(lam_edge, c)
+                    lam_nbr=tuple(port_row(lam_nbr, c)
                                   for c in range(ports)),
                     radius=np.asarray(radius[self.i]),
                     bits=np.asarray(bits[self.i]),

@@ -93,7 +93,7 @@ def test_layerwise_defaults_equal_uniform():
     # bits differ in shape ((W,) vs (W, L)) by design; everything else and
     # the model trajectory must match bitwise
     _assert_states_equal(st_u, st_l, fields=("theta", "theta_hat",
-                                             "hat_edge", "lam_edge",
+                                             "hat_nbr", "lam_nbr",
                                              "radius"))
     np.testing.assert_array_equal(np.asarray(m_u["loss"]),
                                   np.asarray(m_l["loss"]))

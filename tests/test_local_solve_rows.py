@@ -84,7 +84,7 @@ def _all_rows(tr):
 
 
 def _tup(state):
-    return (state.theta, state.theta_hat, state.hat_edge, state.lam_edge,
+    return (state.theta, state.theta_hat, state.hat_nbr, state.lam_nbr,
             state.radius, state.bits, state.opt_mu, state.opt_nu,
             state.opt_t)
 

@@ -232,10 +232,11 @@ def test_check_edge_mirrors_catches_desync():
     step = jax.jit(tr.make_train_step())
     state, _ = step(state, batch)
     lam = jax.tree.map(lambda x: np.array(jax.device_get(x)),
-                       state.lam_edge)
+                       state.lam_nbr)
     leaf = jax.tree.leaves(lam)[0]
-    leaf[0] += 10.0                      # break one directed row's mirror
-    bad = state._replace(lam_edge=jax.tree.map(jnp.asarray, lam))
+    # break directed row 0's mirror, held in its owner slot (dst, color)
+    leaf[tr.eidx.dst[0] * tr.topo.num_ports + tr.eidx.color[0]] += 10.0
+    bad = state._replace(lam_nbr=jax.tree.map(jnp.asarray, lam))
     with pytest.raises(checks.ObsCheckError, match="mirror"):
         checks.check_edge_mirrors(tr, bad)
 

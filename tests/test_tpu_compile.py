@@ -12,7 +12,8 @@ contract tests (test_kernels.py) and on the chip (chip_smoke.py).  Each
 compiled kernel keeps the instruction name by which the benchmark's trace
 reduction (benchmarks/chip/trace_reduce.KERNELS) finds it, in isolation
 and inside the sharded packed round, where the kernels also sit in the
-codec's and the exchange's layer scopes.
+codec's and the exchange's layer scopes.  The same compiled round holds
+only each chip's own worker's neighbor slots and gathers no wire.
 """
 import os
 import re
@@ -136,13 +137,11 @@ def test_kernel_compiles_for_v5e(kernel, size, one_chip, whisper_n):
     assert all(op.endswith(f"/{KERNEL_NAME[kernel]}/pallas_call")
                for op in calls.values()), calls
 
-
-def test_step_kernels_keep_their_trace_names(topo):
+@pytest.fixture(scope="module")
+def sharded_round(topo):
     """The sharded W=4 round with the packed 4-bit wire (whisper-tiny at
-    smoke widths, one worker per chip of v5e:2x2): its Mosaic kernels are
-    the ones trace_reduce.KERNELS finds (`quantize`: the q-only codec,
-    `pack`: pack4 and unpack4) and sit in the codec's and the exchange's
-    layer scopes."""
+    smoke widths, one worker per chip of v5e:2x2), compiled for the chip,
+    with its trainer and the state and batch it takes."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.gadmm import GADMMConfig
@@ -172,8 +171,17 @@ def test_step_kernels_keep_their_trace_names(topo):
         tree, specs, is_leaf=lambda x: isinstance(x, P))
     state = place(state, tr.state_specs(state))
     batch = place(batch, tr.batch_specs(batch))
-    hlo = tr.jit_train_step(state, batch).lower(state, batch).compile(
-        ).as_text()
+    compiled = tr.jit_train_step(state, batch).lower(state, batch).compile()
+    return tr, state, batch, compiled
+
+
+def test_step_kernels_keep_their_trace_names(sharded_round):
+    """The sharded W=4 round with the packed 4-bit wire (whisper-tiny at
+    smoke widths, one worker per chip of v5e:2x2): its Mosaic kernels are
+    the ones trace_reduce.KERNELS finds (`quantize`: the q-only codec,
+    `pack`: pack4 and unpack4) and sit in the codec's and the exchange's
+    layer scopes."""
+    hlo = sharded_round[3].as_text()
     calls = kernel_calls(hlo)
     quantize = {n for n, op in calls.items() if "jit(quantize)/" in op}
     pack = {n for n, op in calls.items()
@@ -189,3 +197,40 @@ def test_step_kernels_keep_their_trace_names(topo):
     assert all("qgadmm.exchange/" in calls[n]
                and re.search(r"/(un)?pack4/pallas_call$", calls[n])
                for n in pack)
+
+
+ALL_GATHER = re.compile(
+    r"=\s*\(?(?P<dtype>\w+)\[(?P<dims>[\d,]*)\][^ ]*\)?\s+all-gather"
+    r"(-start)?\(")
+
+
+def test_sharded_round_holds_only_its_workers_slots(sharded_round):
+    """Each chip of the sharded round holds, decodes and dual-updates only
+    its own worker's neighbor slots: the compiled round all-gathers
+    neither the wire (u8) nor any parameter leaf stacked over the workers
+    (a hat or an edge row), and its per-chip argument bytes for the
+    neighbor state are 2 * C slots of one worker's model (hat and dual,
+    C = 2 colors on the chain), where replicated directed-edge rows were
+    2 * 2E = 12."""
+    tr, state, batch, compiled = sharded_round
+    leaf = min(int(np.prod(l.shape[1:])) for l in jax.tree.leaves(state.theta)
+               if l.size)
+    gathered = []
+    for line in compiled.as_text().splitlines():
+        m = ALL_GATHER.search(line)
+        if m and (m.group("dtype") == "u8" or int(np.prod(
+                [int(x) for x in m.group("dims").split(",") if x]))
+                >= tr.dcfg.num_workers * leaf):
+            gathered.append(line.strip()[:160])
+    assert not gathered, gathered
+
+    def arg_bytes(tree):
+        return jax.jit(lambda x: x).lower(tree).compile(
+            ).memory_analysis().argument_size_in_bytes
+
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert args == arg_bytes((state, batch))
+    rest = arg_bytes((state._replace(hat_nbr=(), lam_nbr=()), batch))
+    c = tr.topo.num_ports
+    assert c == 2 and tr.eidx.num_directed == 6
+    assert args - rest == 2 * c * arg_bytes(state.theta)

@@ -88,7 +88,8 @@ def state_arrays(tr, state, metrics):
     """Flatten (state, metrics) into a {name: ndarray} dict in the GOLDEN
     comparison layout: neighbor hats/duals are projected to per-(worker,
     port-color) views so the dict is independent of the internal state
-    layout (port-dense tuples pre-refactor, edge slabs post)."""
+    layout (port-dense tuples, then directed-edge slabs, now (W, C) owner
+    slots)."""
     out = {}
 
     def put(name, arr):
@@ -100,9 +101,12 @@ def state_arrays(tr, state, metrics):
 
     views = tr.port_views(state) if hasattr(tr, "port_views") else {
         "hat_nbr": state.hat_nbr, "lam_nbr": state.lam_nbr}
-    # edge-indexed states project their slabs to the golden port-view names
+    # states that held directed-edge slabs project them to the golden
+    # port-view names
     alias = {"hat_edge": "hat_nbr", "lam_edge": "lam_nbr"}
     for field in state._fields:
+        if field == "edge_slot":      # the topology's row map, not state
+            continue
         name = alias.get(field, field)
         val = views.get(name, getattr(state, field))
         for i, leaf in enumerate(jax.tree.leaves(val)):
