@@ -106,6 +106,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.random import threefry2x32_p
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import censor as censor_mod
@@ -124,6 +125,7 @@ from repro.kernels.quantize import ref as q_ref
 from . import sharding as sh
 
 Array = jax.Array
+LANES = q_kernel.LANES     # the wire tiles' lane width
 
 _ADAM_B1, _ADAM_B2, _ADAM_EPS = 0.9, 0.999, 1e-8
 
@@ -473,6 +475,53 @@ def _decode(q: Array, hat_f: Array, radius: Array, levels: Array) -> Array:
     return jnp.where(radius > 0, out, hat_f)
 
 
+def _uniform_at(key: Array, hi: Array, lo: Array, n: int) -> Array:
+    """(k, ceil(n / 128), 128) f32 uniforms: row i is the draw at the flat
+    counters c_i + j, j < n, where c_i = hi[i] * 2**32 + lo[i] (uint32
+    (k,) words, static or traced); positions past n are padding.
+
+    Bitwise what `jax.random.uniform` gives at those flat positions of a
+    larger draw (threefry keys, `jax_threefry_partitionable`, the default):
+    element c of a uniform draw is threefry2x32(key, (c >> 32, c & (2**32
+    - 1))) with its two words xor-ed, then the mantissa trick.  The lane-
+    dense (.., 128) shape keeps the VPU-bound hash on full (8, 128) vreg
+    tiles, where a (k, n) draw of few rows runs on k sublanes."""
+    if jax.dtypes.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    assert key.shape == (2,), "the row draw takes a threefry key"
+    m = -(-n // LANES)
+    assert m * LANES < 2 ** 32, n
+    j = (jax.lax.broadcasted_iota(jnp.uint32, (1, m, LANES), 1)
+         * jnp.uint32(LANES)
+         + jax.lax.broadcasted_iota(jnp.uint32, (1, m, LANES), 2))
+    lo = lo.astype(jnp.uint32)[:, None, None]
+    cnt_lo = lo + j                                    # wraps mod 2**32
+    cnt_hi = (hi.astype(jnp.uint32)[:, None, None]
+              + (cnt_lo < lo).astype(jnp.uint32))      # the carry
+    b1, b2 = threefry2x32_p.bind(key[0], key[1], cnt_hi, cnt_lo)
+    one = jnp.uint32(np.float32(1.0).view(np.uint32))
+    bits = ((b1 ^ b2) >> jnp.uint32(32 - 23)) | one
+    return jax.lax.bitcast_convert_type(bits, jnp.float32) - jnp.float32(1.0)
+
+
+def _split_words(starts) -> tuple[Array, Array]:
+    """Static 64-bit counters -> their (hi, lo) uint32 words."""
+    starts = np.asarray(starts, np.uint64)
+    return (jnp.asarray((starts >> np.uint64(32)).astype(np.uint32)),
+            jnp.asarray((starts & np.uint64(2 ** 32 - 1)).astype(np.uint32)))
+
+
+def row_uniform(key: Array, rows, w: int, d_pad: int) -> Array:
+    """The shared uniform draw of the wire rows `rows` (static): bitwise
+    `jax.random.uniform(key, (w, d_pad))[rows]`, as (len(rows),
+    ceil(d_pad / 128), 128) lane-dense tiles (`_uniform_at`), so a phase
+    draws only the rows it codes."""
+    rows = np.asarray(rows, np.int64)
+    assert rows.size and 0 <= rows.min() and rows.max() < w, (rows, w)
+    hi, lo = _split_words(rows * d_pad)
+    return _uniform_at(key, hi, lo, d_pad)
+
+
 def _twhere(m: Array, a, b):
     return jax.tree.map(lambda x, y: jnp.where(_bmask(m, x), x, y), a, b)
 
@@ -532,14 +581,17 @@ class QGADMMTrainer:
 
     def _lw_tables(self, sizes: tuple):
         """Resolved per-leaf (bits, periods, taus) device tables for a flat
-        leaf-size tuple (static; cached per distinct pytree shape)."""
+        leaf-size tuple (static; cached per distinct pytree shape, as
+        concrete arrays even when first asked for inside a trace, so a
+        later trace reuses no tracer)."""
         if sizes not in self._lw_cache:
             bits, periods, taus = self.dcfg.layerwise.resolve(
                 list(sizes), self.dcfg.gadmm.qcfg.bits)
-            self._lw_cache[sizes] = (
-                jnp.asarray(bits, jnp.int32),
-                jnp.asarray(periods, jnp.int32),
-                None if taus is None else jnp.asarray(taus, jnp.float32))
+            with jax.ensure_compile_time_eval():
+                self._lw_cache[sizes] = (
+                    jnp.asarray(bits, jnp.int32),
+                    jnp.asarray(periods, jnp.int32),
+                    None if taus is None else jnp.asarray(taus, jnp.float32))
         return self._lw_cache[sizes]
 
     def _edge_to_slots(self, x: Array) -> Array:
@@ -780,9 +832,9 @@ class QGADMMTrainer:
 
     # ------------------------------------------------------- quantization --
     def _per_leaf_radius(self, leaves, hat_leaves):
-        """(W, L) per-leaf inf-norm radii; zero-size leaves get R = 0 (the
-        same guard quantizer.global_radius applies)."""
-        w = self.dcfg.num_workers
+        """(R, L) per-leaf inf-norm radii of the leaves' R rows; zero-size
+        leaves get R = 0 (the same guard quantizer.global_radius applies)."""
+        w = leaves[0].shape[0] if leaves else self.dcfg.num_workers
         cols = []
         for x, h in zip(leaves, hat_leaves):
             if int(np.prod(x.shape[1:])) == 0:
@@ -795,10 +847,10 @@ class QGADMMTrainer:
         return jnp.stack(cols, axis=1)
 
     def _per_leaf_delta2(self, a_leaves, b_leaves):
-        """(W, L) per-leaf squared L2 distances — the residual-magnitude
-        ranking score of the bit-budget controller and the per-leaf censor
-        statistic (zero-size leaves get 0)."""
-        w = self.dcfg.num_workers
+        """(R, L) per-leaf squared L2 distances of the leaves' R rows — the
+        residual-magnitude ranking score of the bit-budget controller and
+        the per-leaf censor statistic (zero-size leaves get 0)."""
+        w = a_leaves[0].shape[0] if a_leaves else self.dcfg.num_workers
         cols = []
         for x, h in zip(a_leaves, b_leaves):
             if int(np.prod(x.shape[1:])) == 0:
@@ -812,10 +864,11 @@ class QGADMMTrainer:
         return jnp.stack(cols, axis=1)
 
     def _qdq_row(self, theta_row, hat_row, u_row, radius, bits):
-        """Quantize one (d,) wire-row slab -> (q, new hat).  radius is a
-        scalar (global mode) or a (d,) per-element expansion (per_tensor
-        mode); bits is a scalar or a (d,) per-element expansion (layerwise
-        per-leaf widths).
+        """Quantize one (d,) wire-row slab -> (q, new hat).  u_row is the
+        slab's draw as (ceil(d / 128), 128) tiles (`_uniform_at`).  radius
+        is a scalar (global mode) or a (d,) per-element expansion
+        (per_tensor mode); bits is a scalar or a (d,) per-element expansion
+        (layerwise per-leaf widths).
 
         The codec yields q only (the Pallas `quantize` kernel or the jnp
         reference's q); the sender's new hat is `_decode(q)`, the function
@@ -825,17 +878,20 @@ class QGADMMTrainer:
         radius = jnp.asarray(radius, jnp.float32)
         if self.dcfg.wire_impl == "jnp":
             q, _ = q_ref.quantize_dequantize_ref(
-                theta_row, hat_row, u_row, radius, levels)
+                theta_row, hat_row, u_row.reshape(-1)[:theta_row.shape[0]],
+                radius, levels)
         else:
             q = q_kernel.quantize(
                 theta_row, hat_row, u_row, radius, levels,
                 interpret=self.dcfg.wire_impl != "pallas_compiled")
         return q, _decode(q, hat_row, radius, levels)
 
-    def _qdq_sharded(self, theta_f, hat_f, u, radius, bits, seg=None):
+    def _qdq_sharded(self, theta_f, hat_f, key, radius, bits, seg=None):
         """Codec under shard_map: every device runs the quantize (and the
         shared decode of its hat) on exactly the (1, d_loc) wire slab it owns,
-        with its worker's radius/bits riding along the 'worker' axis.
+        with its worker's radius/bits riding along the 'worker' axis.  Each
+        device draws its slab's own counters of the shared (W, D_pad) draw,
+        w * D_pad + slab * d_loc + j, lane-dense (`_uniform_at`).
 
         This keeps the codec internals out of the SPMD partitioner — which
         XLA:CPU miscompiles for the pad/reshape/slice patterns inside the
@@ -866,13 +922,20 @@ class QGADMMTrainer:
             seg_pad = np.full((d_pad,), n_leaves, np.int32)
             seg_pad[:seg.size] = seg
         msize = self.mesh.shape["model"]
+        n_slabs = self._group_size()
+        d_loc = d_pad // n_slabs
+        # first counter of each (worker, slab): 64-bit, so split statically
+        start_hi, start_lo = _split_words(
+            np.arange(self.dcfg.num_workers)[:, None] * d_pad
+            + np.arange(n_slabs)[None, :] * d_loc)
 
-        def body(th, h, uu, rr, bb):
+        def body(th, h, k, rr, bb):
             rr_row, bb_row = rr[0], bb[0]
+            slab = (jax.lax.axis_index("fsdp") * msize
+                    + jax.lax.axis_index("model"))
+            at = (jax.lax.axis_index("worker"), slab)
+            uu = _uniform_at(k, start_hi[at][None], start_lo[at][None], d_loc)
             if seg is not None:
-                d_loc = th.shape[1]
-                slab = (jax.lax.axis_index("fsdp") * msize
-                        + jax.lax.axis_index("model"))
                 seg_loc = jax.lax.dynamic_slice(
                     jnp.asarray(seg_pad), (slab * d_loc,), (d_loc,))
                 if rr.ndim == 2:
@@ -886,38 +949,62 @@ class QGADMMTrainer:
 
         return jax.shard_map(
             body, mesh=self.mesh,
-            in_specs=(bspec, bspec, bspec, rspec, bitspec),
+            in_specs=(bspec, bspec, P(), rspec, bitspec),
             out_specs=(bspec, bspec), check_vma=False)(
-                theta_f, hat_f, u, radius, bits)
+                theta_f, hat_f, key, radius, bits)
+
+    def _fixed_bits(self, sizes: tuple, w: int):
+        """The (w,) or (w, L) bit widths where they never adapt (the
+        configured ones, which every row then holds), else None.  A
+        constant: XLA rewrites x / levels as x * (1 / levels) for a constant
+        divisor, so the two ends of an edge decode bitwise alike only if
+        both see the widths as the same constant."""
+        lw = self.dcfg.layerwise
+        qcfg = self.dcfg.gadmm.qcfg
+        if lw is None:
+            return (None if qcfg.adapt_bits
+                    else jnp.full((w,), qcfg.bits, jnp.int32))
+        if lw.budget_bits is not None or lw.adapt_bits:
+            return None
+        base_b, _, _ = self._lw_tables(sizes)
+        return jnp.broadcast_to(base_b[None], (w, len(sizes)))
 
     def _quantize_all(self, theta, hat, bits_prev, radius_prev, key,
-                      sharded: bool, step_idx=None):
-        """Quantize every worker row on the flat wire buffer.
+                      sharded: bool, step_idx=None, rows=None):
+        """Quantize the worker rows `rows` (static, ascending; None: all W)
+        on the flat wire buffer; theta, hat, bits_prev and radius_prev hold
+        those rows alone, R = len(rows) of them.
 
-        Returns (q_wire (W, D_pad) uint8, hat_new pytree, r_new, b_new,
-        leaf_due) with r_new (W,) in global mode / (W, L) per_tensor.  Bit
+        Returns (q_wire (R, D_pad) uint8, hat_new pytree, r_new, b_new,
+        leaf_due) with r_new (R,) in global mode / (R, L) per_tensor.  Bit
         adaptation (paper eq. 11) tracks the global radius ratio — or, in
         layerwise mode, each leaf's own ratio, unless the bit-budget
         controller (quantizer.allocate_bits) supersedes it.  leaf_due is
-        the (W, L) exchange-period gate in layerwise mode (None otherwise);
+        the (R, L) exchange-period gate in layerwise mode (None otherwise);
         the codec itself always runs on every leaf with the full fresh
         radii, so the shared uniform draw is consumed identically whatever
         the masks — callers zero the PAYLOAD radius of unsent leaves
         instead, which no-ops them on both endpoints.
 
-        Shared uniform-draw convention: ONE jax.random.uniform draw over the
-        padded (W, D_pad) buffer, consumed identically by every wire_impl —
-        the jnp and Pallas paths are bit-identical.
+        Shared uniform-draw convention: row w of ONE
+        jax.random.uniform(key, (W, D_pad)) draw over the padded buffer,
+        consumed identically by every wire_impl (the jnp and Pallas paths
+        are bit-identical) and by every row cut: each coded row draws its
+        own counters (`row_uniform`; on a mesh each device its slab's), so
+        the rows a phase leaves out are never drawn.
         """
         qcfg = self.dcfg.gadmm.qcfg
         lw = self.dcfg.layerwise
-        w = self.dcfg.num_workers
+        w_all = self.dcfg.num_workers
+        rows = np.arange(w_all) if rows is None else np.asarray(rows)
+        assert not sharded or rows.size == w_all, "a mesh codes every row"
+        w = rows.size
         leaves = jax.tree.leaves(theta)
         treedef = jax.tree.structure(theta)
         hat_leaves = treedef.flatten_up_to(hat)
         sizes = _leaf_sizes(leaves)
         n_leaves = len(sizes)
-        per_leaf_r = self._per_leaf_radius(leaves, hat_leaves)  # (W, L)
+        per_leaf_r = self._per_leaf_radius(leaves, hat_leaves)  # (R, L)
         r_global = (jnp.max(per_leaf_r, axis=1) if per_leaf_r.shape[1]
                     else jnp.zeros((w,), jnp.float32))
         leaf_due = None
@@ -935,16 +1022,16 @@ class QGADMMTrainer:
                 b_new = _next_bits(self._lw_qcfg, bits_prev, per_leaf_r,
                                    radius_prev, base_bits=base_b[None])
             else:
-                b_new = jnp.broadcast_to(base_b[None], (w, n_leaves))
+                b_new = self._fixed_bits(tuple(sizes), w)
             leaf_due = jnp.broadcast_to((step_idx % periods) == 0,
                                         (w, n_leaves))
             r_new = per_leaf_r
         elif qcfg.adapt_bits:
             r_prev = (radius_prev if radius_prev.ndim == 1
                       else jnp.max(radius_prev, axis=1))
-            b_new = _next_bits(qcfg, bits_prev, r_global, r_prev)  # (W,)
+            b_new = _next_bits(qcfg, bits_prev, r_global, r_prev)  # (R,)
         else:
-            b_new = jnp.full((w,), qcfg.bits, jnp.int32)
+            b_new = self._fixed_bits(tuple(sizes), w)
         if lw is None:
             r_new = (r_global if self.dcfg.radius_mode == "global"
                      else per_leaf_r)
@@ -957,7 +1044,6 @@ class QGADMMTrainer:
         theta_f = self._pad_wire(self._flatten_rows(leaves, jnp.float32))
         hat_f = self._pad_wire(self._flatten_rows(hat_leaves, jnp.float32))
         d_pad = theta_f.shape[1]
-        u = jax.random.uniform(key, (w, d_pad), jnp.float32)
         per_tensor = self.dcfg.radius_mode == "per_tensor"
         seg = (np.repeat(np.arange(n_leaves), sizes)           # (D,)
                if (per_tensor or lw is not None) else None)
@@ -968,7 +1054,7 @@ class QGADMMTrainer:
             # partitioner must shard along the gathered dimension, which
             # XLA:CPU miscompiles (see _qdq_sharded)
             q_wire, hat_new_f = self._qdq_sharded(
-                theta_f, hat_f, u,
+                theta_f, hat_f, key,
                 per_leaf_r if per_tensor else r_global,
                 b_new, seg=seg)
         else:
@@ -988,14 +1074,15 @@ class QGADMMTrainer:
                     b_pos = jnp.pad(b_pos, ((0, 0), (0, d_pad - d)),
                                     constant_values=1)
                 b_arg = b_pos                                  # (W, D_pad)
+            u = row_uniform(key, rows, w_all, d_pad)    # (R, D_pad/128, 128)
             q_rows, hat_rows = [], []
             for i in range(w):
                 q_i, h_i = self._qdq_row(theta_f[i], hat_f[i], u[i],
                                          r_arg[i], b_arg[i])
                 q_rows.append(q_i)
                 hat_rows.append(h_i)
-            q_wire = jnp.stack(q_rows)                 # (W, D_pad) uint8
-            hat_new_f = jnp.stack(hat_rows)            # (W, D_pad) f32
+            q_wire = jnp.stack(q_rows)                 # (R, D_pad) uint8
+            hat_new_f = jnp.stack(hat_rows)            # (R, D_pad) f32
         hat_new = self._unflatten_cast(hat_new_f, hat_leaves, treedef)
         return q_wire, hat_new, r_new, b_new, leaf_due
 
@@ -1112,12 +1199,13 @@ class QGADMMTrainer:
         its own row from a local view whose other rows are garbage — the
         contract repro.sim.worker.TrainerActor builds on.
 
-        `rows` (static indices, ascending) runs the local solve on those
-        workers alone and commits only their rows: `active` is cut to
-        them, every other row of the state is returned as it came in and
-        sends nothing, and f0 is 0 there.  None solves every row (the
-        simulator's dynamic masks need that).  The codec still codes all
-        W rows: its uniform draw spans (W, D)."""
+        `rows` (static indices, ascending) runs the local solve and the
+        codec on those workers alone and commits only their rows: `active`
+        is cut to them, every other row of the state is returned as it came
+        in, and f0 is 0 there.  The payload keeps its (W, ...) form: the
+        other rows send nothing (`sent` False, a zero wire, the stored
+        radius and bits).  None solves and codes every row (the simulator's
+        dynamic masks need that)."""
         g = self.dcfg.gadmm
         cc = self.dcfg.censor
         w = self.dcfg.num_workers
@@ -1132,23 +1220,30 @@ class QGADMMTrainer:
             sub = lambda tree: jax.tree.map(lambda a: _rows(a, rows), tree)
             put = lambda full, part: jax.tree.map(
                 lambda a, b: _put_rows(a, rows, b), full, part)
+        # a (W, ...) payload leaf: the coded rows, zeros elsewhere
+        fill = lambda part: put(
+            jnp.zeros((w,) + part.shape[1:], part.dtype), part)
+        act = sub(active)
         with _layer("local_solve"):
-            act = sub(active)
             old = sub((theta, mu, nu, t))
             new_theta, new_mu, new_nu, new_t, f0 = jax.vmap(self._local_opt)(
                 *old, sub(batch), self._port_view(lam_nbr, rows),
                 self._port_view(hat_nbr, rows), sub(pw), sub(self.sign))
-            theta = put(theta, _twhere(act, new_theta, old[0]))
+            theta_r = _twhere(act, new_theta, old[0])
+            theta = put(theta, theta_r)
             mu = put(mu, _twhere(act, new_mu, old[1]))
             nu = put(nu, _twhere(act, new_nu, old[2]))
             t = put(t, jnp.where(act, new_t, old[3]))
-            f0 = put(jnp.zeros((w,), f0.dtype), f0)
+            f0 = fill(f0)
 
         with _layer("codec"):
+            hat_r, radius_r, bits_r = sub((hat, radius, bits))
             if g.quantize:
                 q_wire, hat_new, r_new, b_new, leaf_due = self._quantize_all(
-                    theta, hat, bits, radius, key, sharded, step_idx)
+                    theta_r, hat_r, bits_r, radius_r, key, sharded, step_idx,
+                    rows)
                 lw = self.dcfg.layerwise
+                eff_leaf = None
                 if lw is not None:
                     # L-FGADMM leaf gating: a leaf is transmitted only on its
                     # period rounds, and (with per-leaf taus) only when its
@@ -1158,69 +1253,69 @@ class QGADMMTrainer:
                     # worker-level censor below sees the true delta and both
                     # endpoints stay bit-synced (unsent leaves ride the payload
                     # with radius 0, a codec no-op for every receiver).
-                    treedef = jax.tree.structure(hat)
+                    treedef = jax.tree.structure(hat_r)
                     hn = treedef.flatten_up_to(hat_new)
-                    ho = treedef.flatten_up_to(hat)
+                    ho = treedef.flatten_up_to(hat_r)
                     leaf_sent = leaf_due
                     _, _, taus = self._lw_tables(
-                        tuple(_leaf_sizes(jax.tree.leaves(theta))))
+                        tuple(_leaf_sizes(jax.tree.leaves(theta_r))))
                     if taus is not None:
                         thr = taus * jnp.power(
                             jnp.float32(lw.tau_xi),
                             jnp.asarray(step_idx, jnp.float32))    # (L,)
                         delta = jnp.sqrt(self._per_leaf_delta2(hn, ho))
                         leaf_sent = leaf_sent & (delta > thr)
-                    hat_cand = jax.tree.unflatten(treedef, [
+                    hat_new = jax.tree.unflatten(treedef, [
                         jnp.where(_bmask(leaf_sent[:, i], a), a, b)
                         for i, (a, b) in enumerate(zip(hn, ho))])
-                    if cc is not None:
-                        sent = active & censor_mod.transmit_mask(
-                            hat_cand, hat, cc, step_idx)
-                    else:
-                        sent = active
-                    eff_leaf = leaf_sent & sent[:, None]           # (W, L)
-                    hat = _twhere(sent, hat_cand, hat)
-                    radius = jnp.where(eff_leaf, r_new, radius)
-                    bits = jnp.where(eff_leaf, b_new, bits)
-                    payload = {"wire": self._finish_wire(q_wire),
-                               "radius": jnp.where(eff_leaf, r_new, 0.0),
-                               "bits": b_new, "sent": sent,
-                               "leaf_sent": eff_leaf}
+                # CQ-GGADMM censoring: commit + transmit only when the
+                # quantized model moved past the decaying threshold.
+                # hat_new is the committed (per-leaf-cast) value, so the
+                # mask is identical for every wire_impl and on both the
+                # unsharded and sharded paths.
+                sent = act
+                if cc is not None:
+                    sent = sent & censor_mod.transmit_mask(
+                        hat_new, hat_r, cc, step_idx)
+                if lw is not None:
+                    eff_leaf = leaf_sent & sent[:, None]           # (R, L)
+                    keep, pay_r = eff_leaf, jnp.where(eff_leaf, r_new, 0.0)
                 else:
-                    if cc is not None:
-                        # CQ-GGADMM censoring: commit + transmit only when
-                        # the quantized model moved past the decaying
-                        # threshold.  hat_new is the committed (per-leaf-cast)
-                        # value, so the mask is identical for every wire_impl
-                        # and on both the unsharded and sharded paths.
-                        sent = active & censor_mod.transmit_mask(
-                            hat_new, hat, cc, step_idx)
-                    else:
-                        sent = active
-                    hat = _twhere(sent, hat_new, hat)
-                    radius = jnp.where(_bmask(sent, r_new), r_new, radius)
-                    bits = jnp.where(sent, b_new, bits)
-                    payload = {"wire": self._finish_wire(q_wire),
-                               "radius": r_new, "bits": b_new, "sent": sent}
+                    keep, pay_r = _bmask(sent, r_new), r_new
+                hat = put(hat, _twhere(sent, hat_new, hat_r))
+                radius = put(radius, jnp.where(keep, r_new, radius_r))
+                bits = put(bits, jnp.where(
+                    sent if lw is None else keep, b_new, bits_r))
+                fixed = self._fixed_bits(
+                    tuple(_leaf_sizes(jax.tree.leaves(theta))), w)
+                payload = {"wire": fill(self._finish_wire(q_wire)),
+                           "radius": put(radius, pay_r),
+                           "bits": put(bits, b_new) if fixed is None
+                           else fixed,
+                           "sent": fill(sent)}
+                if eff_leaf is not None:
+                    payload["leaf_sent"] = fill(eff_leaf)
             else:
                 # full-precision GADMM: track the would-be radius for metrics,
                 # then "transmit" theta itself (hat == theta).  Censoring
                 # applies identically (this is C-GGADMM).
                 per_leaf_r = self._per_leaf_radius(
-                    jax.tree.leaves(theta), jax.tree.leaves(hat))  # (W, L)
+                    jax.tree.leaves(theta_r), jax.tree.leaves(hat_r))
+                sent = act
                 if cc is not None:
-                    sent = active & censor_mod.transmit_mask(
-                        theta, hat, cc, step_idx)
-                else:
-                    sent = active
-                hat = _twhere(sent, theta, hat)
+                    sent = sent & censor_mod.transmit_mask(
+                        theta_r, hat_r, cc, step_idx)
+                hat_r = _twhere(sent, theta_r, hat_r)
                 r_new = (jnp.max(per_leaf_r, axis=1)
                          if radius.ndim == 1 and per_leaf_r.shape[1]
                          else (per_leaf_r if radius.ndim > 1
-                               else jnp.zeros((w,), jnp.float32)))
-                radius = jnp.where(_bmask(sent, r_new), r_new, radius)
-                payload = {"wire": self._flatten_wire(
-                    jax.tree.leaves(hat), jnp.float32), "sent": sent}
+                               else jnp.zeros(sent.shape, jnp.float32)))
+                hat = put(hat, hat_r)
+                radius = put(radius, jnp.where(_bmask(sent, r_new), r_new,
+                                               radius_r))
+                payload = {"wire": fill(self._flatten_wire(
+                    jax.tree.leaves(hat_r), jnp.float32)),
+                    "sent": fill(sent)}
 
         return (theta, hat, hat_nbr, lam_nbr, radius, bits,
                 mu, nu, t), payload, f0
@@ -1306,6 +1401,13 @@ class QGADMMTrainer:
         stale = self.dcfg.staleness > 0 and w > 1 and self.topo.num_edges > 0
         two_phase = stale or (self.dcfg.mode == "gauss-seidel" and w > 1)
         return 2 * w if two_phase and self._groups(sharded)[0] is None else w
+
+    def codec_rows(self, sharded: bool) -> int:
+        """Worker rows the codec draws and codes in one round: each phase
+        codes the rows it solves (`phase_compute`'s `rows`), so W where the
+        workers share one device (Gauss-Seidel or Jacobi), 2W on the
+        worker-sharded Gauss-Seidel round."""
+        return self.local_solves(sharded)
 
     def _merge_f0(self, f0_h, f0_t, rows_h):
         """The round's start-of-round data losses: all of phase 1's when it

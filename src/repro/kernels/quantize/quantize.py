@@ -98,7 +98,12 @@ def _fused(theta, theta_hat_prev, u, radius, levels, interpret, with_hat):
 
     theta2 = to2d(theta, 0)
     hat2 = to2d(theta_hat_prev, 0)
-    u2 = to2d(u.astype(jnp.float32), 1.0)  # u=1 never rounds up on padding
+    if u.shape == (rows, cols):
+        # already the kernel's tiles (the trainer's lane-dense row draw):
+        # no relayout; draws past n only touch padding, dropped below
+        u2 = u.astype(jnp.float32)
+    else:
+        u2 = to2d(u.astype(jnp.float32), 1.0)  # u=1 never rounds up on padding
 
     block_m = min(BLOCK_M, rows)
     scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
@@ -173,7 +178,8 @@ def quantize(
     interpret: bool = True,
 ) -> Array:
     """The levels q of `quantize_dequantize` alone (same variants, same
-    arithmetic): 3 reads and a 1-byte write per element.  The dist
+    arithmetic): 3 reads and a 1-byte write per element.  `u` is theta's
+    shape or the kernel's own (ceil(n / 128), 128) tiles.  The dist
     trainer's sender uses it and decodes its new hat from q with the
     receivers' own function, so both ends of an edge agree by
     construction."""

@@ -235,7 +235,8 @@ def build(args: argparse.Namespace, **dist_overrides) -> Run:
         dcfg, seed=0, topology=args.topology, num_workers=args.workers,
         extra={"cli": "launch.train", "arch": args.arch,
                "steps": args.steps, "mesh": dict(wmesh.shape),
-               "local_solves": trainer.local_solves(sharded)})
+               "local_solves": trainer.local_solves(sharded),
+               "codec_rows": trainer.codec_rows(sharded)})
     mlog = record.MetricsLog(path=args.metrics_out, manifest=manifest,
                              log_every=args.log_every)
     return Run(trainer=trainer, state=state, step_fn=step_fn,

@@ -39,7 +39,8 @@ def test_train_colocated_workers_on_one_device(tmp_path):
     """One device, W=2: the single-device step with all workers on it,
     invariants checked, compile cache in $JAX_COMPILATION_CACHE_DIR, keyed
     by the program's metadata (the layer scopes) too; the run record's
-    manifest counts one local solve per worker a round."""
+    manifest counts one local solve and one coded row per worker a
+    round."""
     rec = tmp_path / "run.jsonl"
     out = _python(f"""
         import jax
@@ -56,7 +57,9 @@ def test_train_colocated_workers_on_one_device(tmp_path):
     assert "keyed by metadata True" in out, out
     assert any(p != rec for p in tmp_path.iterdir()), \
         "no compiled program was cached"
-    assert _manifest(rec)["local_solves"] == 2
+    manifest = _manifest(rec)
+    assert manifest["local_solves"] == 2
+    assert manifest["codec_rows"] == 2
 
 
 def test_train_emulated_mesh_one_worker_per_device(tmp_path):
@@ -78,7 +81,9 @@ def test_train_emulated_mesh_one_worker_per_device(tmp_path):
     assert "devices 4 cpu" in out, out
     repo = os.path.abspath(os.path.join(SRC, ".."))
     assert f"cache_dir {os.path.join(repo, '.jax_cache')}" in out, out
-    assert _manifest(rec)["local_solves"] == 8
+    manifest = _manifest(rec)
+    assert manifest["local_solves"] == 8
+    assert manifest["codec_rows"] == 8
 
 
 def test_worker_mesh_from_present_devices():
